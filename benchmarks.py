@@ -2,7 +2,7 @@
 
 The reference publishes no numbers (BASELINE.md), so this suite ESTABLISHES
 the baseline: per config it reports kNN queries/sec and the cross-shard
-exchange bandwidth derived from the phase timers (obs/timers.py).
+exchange bandwidth measured by parallel/ring.py measure_exchange_bandwidth.
 
 Each config runs in its own subprocess with its own device environment:
 single-chip configs use the real TPU when reachable; multi-shard configs use
@@ -114,7 +114,7 @@ else:
 
 if shards > 1:
     # MEASURED per-round rotation bandwidth (ppermute minus no-comm
-    # control, parallel/ring.py) next to the analytic phase-level figure
+    # control, parallel/ring.py)
     from mpi_cuda_largescaleknn_tpu.parallel.ring import (
         measure_exchange_bandwidth,
     )
@@ -139,12 +139,9 @@ print("RESULT " + json.dumps({
     "seconds": round(dt, 3),
     "device_seconds": ring.get("seconds"),
     # headline exchange figure: the MEASURED per-link rotation bandwidth
-    # (parallel/ring.py measure_exchange_bandwidth) when available; the
-    # phase-timer analytic figure only as a fallback (it reads 0.0 when the
-    # phase timers attribute no bytes to the ring phase)
-    "exchange_GB_per_sec": (
-        extra.get("exchange_measured", {}).get(
-            "exchange_GB_per_sec_per_link") or ring.get("GB/s", 0.0)),
+    # (parallel/ring.py measure_exchange_bandwidth), None when not measured
+    "exchange_GB_per_sec": extra.get("exchange_measured", {}).get(
+        "exchange_GB_per_sec_per_link"),
     "stats": getattr(model, "last_stats", None),
     **cr, **extra,
 }), flush=True)

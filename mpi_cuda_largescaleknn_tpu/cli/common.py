@@ -42,8 +42,14 @@ TPU-side options (no reference analogue):
                     the top survivors — same final results whenever the
                     true top-k sits inside the rescore window; see
                     docs/TUNING.md "Distance kernel")
-  --profile-dir D   write a jax.profiler trace
-  --timings         print phase timings as JSON to stderr
+  --profile-dir D   write a jax.profiler trace; its host events name the
+                    self-join's steps (selfjoin.shard_and_pad, selfjoin.ring,
+                    selfjoin.extract) and the ring's (ring.stage,
+                    ring.partition, ring.launch: the fused program's
+                    trace/lower/load and enqueue, ring.wait: the device)
+  --timings         print phase timings as JSON to stderr: calls and
+                    seconds per phase (shard_and_pad, ring, extract) and
+                    per ring span (ring.stage ... ring.wait)
   --checkpoint-dir D  snapshot exchange state between rounds (both
                     pipelines); an interrupted run relaunched with the same
                     args resumes at the lost round

@@ -128,7 +128,10 @@ SERVE_FLAGS = """
                     KNN_FAULTS env var / POST /faults (serve/faults.py)
   --no-warmup       skip compiling all shape buckets before serving
                     (first request per bucket then pays the compile)
-  --timings         print engine phase timings as JSON on shutdown
+  --timings         print the engine's phases and spans (serve.http,
+                    serve.queue, serve.engine.stage, ...; docs/SERVING.md
+                    "Spans") as JSON on shutdown; /stats carries the same
+                    under engine.timers
   --verbose         log each HTTP request to stderr
 
 Multi-host (pod) mode — launch ONE copy per host with the same args plus

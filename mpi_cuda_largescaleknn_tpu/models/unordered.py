@@ -56,7 +56,8 @@ class UnorderedKNN:
         if return_neighbors:
             check_neighbor_id_capacity(n_total)
 
-        with self.timers.phase("shard_and_pad"):
+        with self.timers.phase("shard_and_pad",
+                               span="selfjoin.shard_and_pad"):
             bounds = slab_bounds(n_total, num_shards)
             shards = [points[b:e] for b, e in bounds]
             flat, ids, counts, npad = pad_and_flatten(
@@ -64,17 +65,7 @@ class UnorderedKNN:
                 dim=int(np.asarray(points).shape[-1]))
 
         cands = None
-        # tree bytes x rotations: the bidirectional sweep rotates two
-        # copies per device for ring_total_rounds-1 rounds (the final
-        # round is fold-only); the chunked path repeats that per chunk
-        from mpi_cuda_largescaleknn_tpu.parallel.ring import (
-            ring_total_rounds,
-        )
-        n_chunks = (max(1, -(-npad // cfg.query_chunk))
-                    if cfg.query_chunk > 0 else 1)
-        rotations = 2 * (ring_total_rounds(num_shards) - 1)
-        with self.timers.phase("ring", bytes_moved=(
-                num_shards * npad * 12 * rotations * n_chunks)):
+        with self.timers.phase("ring", span="selfjoin.ring"):
             if cfg.query_chunk > 0:
                 got = ring_knn_chunked(
                     flat, ids, cfg.k, self.mesh, max_radius=cfg.max_radius,
@@ -103,7 +94,8 @@ class UnorderedKNN:
                     point_tile=cfg.point_tile, bucket_size=cfg.bucket_size,
                     point_group=cfg.point_group,
                     score_dtype=cfg.score_dtype,
-                    return_candidates=return_neighbors, return_stats=True)
+                    return_candidates=return_neighbors, return_stats=True,
+                    timers=self.timers)
             # last_stats["engine"] is the kernel the driver dispatched
             if return_neighbors:
                 dists, cands, self.last_stats = got
@@ -111,7 +103,7 @@ class UnorderedKNN:
                 dists, self.last_stats = got
             dists = np.asarray(dists)
 
-        with self.timers.phase("extract"):
+        with self.timers.phase("extract", span="selfjoin.extract"):
             out = np.concatenate(trim_per_shard(dists, counts, npad))
             if return_neighbors:
                 idx = np.concatenate(

@@ -1,19 +1,27 @@
-"""Phase timers + bandwidth counters + latency histograms.
+"""Spans, counters and latency histograms.
 
 The reference never measures itself (SURVEY.md §5: no timers anywhere, stdout
 progress lines only) — this subsystem is the capability the TPU build adds so
-BASELINE numbers can be produced at all. Wall-clock per phase, optional bytes
-moved (for cross-shard exchange bandwidth), queries/sec derivation, and
-log-bucketed latency histograms (p50/p95/p99) shared by ``--timings``, the
-serving layer's ``/metrics`` endpoint, and ``tools/loadgen.py``.
+BASELINE numbers can be produced at all. A span (``PhaseTimers.phase``) adds
+its wall-clock to a per-name aggregate and, while a ``jax.profiler`` trace
+is being collected, writes a host event on the profiler's clock, so a
+trace's idle gaps name the host work that left the device waiting.
+Log-bucketed latency histograms (p50/p95/p99) are shared by ``--timings``,
+the serving layer's ``/metrics`` endpoint, and ``tools/loadgen.py``.
+
+JAX is never imported here: a span looks for ``jax.profiler`` only once the
+process has imported JAX itself (a process without JAX runs no profiler),
+so the JAX-free load generator imports this module too.
 """
 
 from __future__ import annotations
 
 import bisect
 import contextlib
+import contextvars
 import json
 import math
+import sys
 import threading
 import time
 from dataclasses import dataclass, field
@@ -122,15 +130,99 @@ def labeled_metric_lines(name: str, rows, kind: str = "counter"):
     return lines
 
 
+#: tags that every span opened in the current context carries as profiler
+#: metadata (``span_tags``): the serving batcher tags its engine calls
+#: with the batch id this way, since the engine never sees the batcher
+_TAGS: contextvars.ContextVar[dict] = contextvars.ContextVar(
+    "span_tags", default={})
+
+
+@contextlib.contextmanager
+def span_tags(**tags):
+    """Add ``tags`` (e.g. ``batch=7``) to every span opened inside the
+    block on this thread. Another thread does not inherit them: a caller
+    that hands work to a pool passes ``current_span_tags()`` along."""
+    token = _TAGS.set({**_TAGS.get(), **tags})
+    try:
+        yield
+    finally:
+        _TAGS.reset(token)
+
+
+def current_span_tags() -> dict:
+    return _TAGS.get()
+
+
+_annotation = None
+
+
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation``, or None while the process has not
+    imported JAX (then no profiler can be running)."""
+    global _annotation
+    if _annotation is None and sys.modules.get("jax") is not None:
+        from jax.profiler import TraceAnnotation
+
+        _annotation = TraceAnnotation
+    return _annotation
+
+
+# spans that closed while a profiler was collecting, over the whole
+# process: {trace name: [count, seconds]}
+_traced_lock = threading.Lock()
+_traced: dict[str, list] = {}
+
+
+def _note_traced(name: str, seconds: float) -> None:
+    ann = _trace_annotation()
+    if ann is not None and ann.is_enabled():
+        with _traced_lock:
+            agg = _traced.setdefault(name, [0, 0.0])
+            agg[0] += 1
+            agg[1] += seconds
+
+
+def traced_spans_snapshot() -> dict:
+    """``{name: (count, seconds)}`` of every span of this process that
+    closed while a ``jax.profiler`` trace was being collected, under the
+    name the trace shows: the spans of the traced slices, summed without
+    reading the trace back."""
+    with _traced_lock:
+        return {n: (c, s) for n, (c, s) in _traced.items()}
+
+
+class _Span:
+    """One ``PhaseTimers.phase`` block: a ``perf_counter`` pair and an
+    aggregate update, plus a profiler event only while a trace is being
+    collected (an annotation is not even built otherwise)."""
+
+    __slots__ = ("timers", "name", "span", "meta", "ann", "t0")
+
+    def __init__(self, timers: "PhaseTimers", name: str, span: str | None,
+                 meta: dict):
+        self.timers, self.name, self.span, self.meta = timers, name, span, meta
+
+    def __enter__(self) -> "_Span":
+        ann = _trace_annotation()
+        self.ann = None
+        if ann is not None and ann.is_enabled():
+            self.ann = ann(self.span or self.name, **{**_TAGS.get(),
+                                                      **self.meta})
+            self.ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.timers.record(self.name, time.perf_counter() - self.t0,
+                           span=self.span)
+        if self.ann is not None:
+            self.ann.__exit__(*exc)
+
+
 @dataclass
 class PhaseRecord:
     seconds: float = 0.0
     calls: int = 0
-    bytes_moved: int = 0
-
-    @property
-    def gb_per_sec(self) -> float:
-        return (self.bytes_moved / self.seconds / 1e9) if self.seconds else 0.0
 
 
 @dataclass
@@ -141,17 +233,34 @@ class PhaseTimers:
     counters: dict[str, int] = field(default_factory=dict)
     _counter_lock: threading.Lock = field(default_factory=threading.Lock,
                                           repr=False)
+    _phase_lock: threading.Lock = field(default_factory=threading.Lock,
+                                        repr=False)
 
-    @contextlib.contextmanager
-    def phase(self, name: str, bytes_moved: int = 0):
-        rec = self.phases.setdefault(name, PhaseRecord())
-        t0 = time.perf_counter()
-        try:
-            yield rec
-        finally:
-            rec.seconds += time.perf_counter() - t0
+    def phase(self, name: str, span: str | None = None, **meta) -> "_Span":
+        """Time the block as phase ``name`` (``phases[name]``: calls and
+        seconds). While a ``jax.profiler`` trace is collected the block is
+        also a host event named ``span`` (default ``name``) whose metadata
+        is ``meta`` plus the context's ``span_tags``, e.g. ``req=12``."""
+        return _Span(self, name, span, meta)
+
+    def record(self, name: str, seconds: float,
+               span: str | None = None) -> None:
+        """Add one span of ``seconds`` to ``name``'s aggregate without a
+        profiler event: for a wait that begins on one thread and ends on
+        another (a profiler event opens and closes on one thread)."""
+        with self._phase_lock:
+            rec = self.phases.get(name)
+            if rec is None:
+                rec = self.phases[name] = PhaseRecord()
+            rec.seconds += seconds
             rec.calls += 1
-            rec.bytes_moved += bytes_moved
+        _note_traced(span or name, seconds)
+
+    def spans_snapshot(self) -> dict:
+        """``{name: (count, seconds)}`` of every phase, for before/after
+        deltas across a window (as ``counters_snapshot`` for counters)."""
+        with self._phase_lock:
+            return {n: (r.calls, r.seconds) for n, r in self.phases.items()}
 
     def hist(self, name: str) -> LatencyHistogram:
         """Named latency histogram (created on first use); shows up in
@@ -190,9 +299,8 @@ class PhaseTimers:
     def report(self) -> dict:
         # list() snapshots: a serving /stats scrape may race a worker thread
         # inserting a new phase or histogram mid-iteration
-        out = {name: {"seconds": round(r.seconds, 6), "calls": r.calls,
-                      **({"GB/s": round(r.gb_per_sec, 3)} if r.bytes_moved else {})}
-               for name, r in list(self.phases.items())}
+        out = {name: {"seconds": round(s, 6), "calls": c}
+               for name, (c, s) in self.spans_snapshot().items()}
         for name, h in list(self.histograms.items()):
             out[name] = h.report()
         for name, v in list(self.gauges.items()):

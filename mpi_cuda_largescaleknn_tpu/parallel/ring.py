@@ -48,6 +48,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from mpi_cuda_largescaleknn_tpu.core.types import CandidateState
+from mpi_cuda_largescaleknn_tpu.obs.timers import PhaseTimers
 from mpi_cuda_largescaleknn_tpu.ops.brute_force import knn_update_bruteforce
 from mpi_cuda_largescaleknn_tpu.ops.build_tree import build_tree
 from mpi_cuda_largescaleknn_tpu.ops.candidates import (
@@ -621,7 +622,8 @@ def ring_knn(points_sharded: jnp.ndarray, ids_sharded: jnp.ndarray, k: int,
              bucket_size: int = 0, point_group: int = 0,
              score_dtype: str = "f32",
              return_candidates: bool = False,
-             return_stats: bool = False):
+             return_stats: bool = False,
+             timers: PhaseTimers | None = None):
     """Run the full R-round ring on a 1-D mesh (fused ``lax.fori_loop``).
 
     Args:
@@ -633,11 +635,16 @@ def ring_knn(points_sharded: jnp.ndarray, ids_sharded: jnp.ndarray, k: int,
         with the rotating shards so candidate lists can report neighbor
         identities (the reference computes these but discards them).
       k / max_radius: the `-k` / `-r` CLI parameters.
+      timers: the caller's ``PhaseTimers``, which get the spans
+        ``ring.stage`` (upload), ``ring.partition``, ``ring.launch`` (the
+        fused program's call: trace, lower, load or compile, enqueue) and
+        ``ring.wait`` (the tile-count fetch, which waits for the device).
 
     Returns:
       f32[R*Npad] k-th-NN distances in the same shard-major order (inf for
       padding rows), plus the CandidateState if ``return_candidates``.
     """
+    timers = timers if timers is not None else PhaseTimers()
     engine = resolve_engine(engine)
     bucket_size = resolve_bucket_size(bucket_size, engine)
     num_shards = mesh.shape[AXIS]
@@ -689,23 +696,26 @@ def ring_knn(points_sharded: jnp.ndarray, ids_sharded: jnp.ndarray, k: int,
         check_vma=check_vma))
 
     sharding = NamedSharding(mesh, shard_spec)
-    points_sharded = jax.device_put(points_sharded, sharding)
-    ids_sharded = jax.device_put(ids_sharded, sharding)
+    with timers.phase("ring.stage"):
+        points_sharded = jax.device_put(points_sharded, sharding)
+        ids_sharded = jax.device_put(ids_sharded, sharding)
+    args = (points_sharded, ids_sharded)
     if init_from_q is not None:
         # tiled path: the log2(B) partition sort passes compile ONCE outside
         # the fused program instead of once per level inside it
-        q_parts = partition_sharded(points_sharded, ids_sharded, mesh,
-                                    bucket_size)
-        dists, hd2, hidx, tiles = mapped(points_sharded, ids_sharded,
-                                         q_parts)
-    else:
-        dists, hd2, hidx, tiles = mapped(points_sharded, ids_sharded)
+        with timers.phase("ring.partition"):
+            args += (partition_sharded(points_sharded, ids_sharded, mesh,
+                                       bucket_size),)
+    with timers.phase("ring.launch"):
+        dists, hd2, hidx, tiles = mapped(*args)
     out = (dists,)
     if return_candidates:
         out += (CandidateState(hd2, hidx),)
     if return_stats:
+        with timers.phase("ring.wait"):
+            tiles_total = int(np.asarray(tiles).sum())
         out += (_ring_stats(
-            kernel, int(np.asarray(tiles).sum())
+            kernel, tiles_total
             + _warm_tiles(engine, npad_local, bucket_size, num_shards),
             bucket_size,
             num_shards * num_shards * npad_local * npad_local,

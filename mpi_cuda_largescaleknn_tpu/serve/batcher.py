@@ -42,6 +42,18 @@ and ``complete`` wall-clock is accounted separately
 (``batch_complete_seconds`` histogram, ``complete_seconds_total`` in
 stats) so escalation cost is attributable instead of vanishing into
 dispatch stalls.
+
+Spans (obs/timers.py, on the shared ``timers``): ``serve.queue`` is each
+request's wait from ``submit`` to the pop that flushes it (aggregate only:
+it starts on the caller's thread and ends on the worker's), whatever held
+it — the flush deadline, coalescing or a pipeline slot;
+``serve.batcher.wait`` is the dispatch worker parked for work or for a
+flush deadline, so a profiler trace names the device's idle time "no
+request due"; ``serve.batcher.stall`` is the blocking pipeline-slot wait;
+``serve.demux`` delivers a batch's rows. The engine calls of a batch run
+under ``span_tags(batch=<id>)`` (the batcher's batch count at pop), and
+``serve.demux`` lists the request ids it answered, so a trace joins each
+request to the engine batch that served it.
 """
 
 from __future__ import annotations
@@ -55,7 +67,7 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from mpi_cuda_largescaleknn_tpu.analysis import guarded_by
-from mpi_cuda_largescaleknn_tpu.obs.timers import LatencyHistogram
+from mpi_cuda_largescaleknn_tpu.obs.timers import PhaseTimers, span_tags
 from mpi_cuda_largescaleknn_tpu.serve.admission import DeadlineExceeded
 
 
@@ -74,6 +86,10 @@ class _Request:
     #: certified per-row init radii (serve/qcache.py seed_for; None =
     #: unseeded). f32[rows]; +inf rows are unseeded. Exact-tier only.
     seeds: np.ndarray | None = None
+    #: the HTTP request id (serve/server.py), listed by ``serve.demux``
+    req: int | None = None
+    #: the id of the batch that popped it (the batcher's batch count)
+    batch: int = 0
     done: threading.Event = field(default_factory=threading.Event)
     result: tuple | None = None
     error: Exception | None = None
@@ -136,7 +152,7 @@ class DynamicBatcher:
         #: bucket, which is what the padded program pays for anyway.
         self.min_batch = int(min_batch) if min_batch else self.max_batch
         self.max_delay_s = float(max_delay_s)
-        self._timers = timers
+        self._timers = timers if timers is not None else PhaseTimers()
         self.pipeline_depth = int(pipeline_depth)
         self.pipelined = (self.pipeline_depth > 1
                           and hasattr(query_fn, "dispatch")
@@ -175,13 +191,11 @@ class DynamicBatcher:
         self.dispatch_stalls: guarded_by("_cond") = 0
         self.dispatch_stall_seconds: guarded_by("_cond") = 0.0
         self.prefetch_hint_errors: guarded_by("_cond") = 0
-        self.stall_hist = (timers.hist("pipeline_stall_seconds")
-                           if timers is not None else LatencyHistogram())
+        self.stall_hist = self._timers.hist("pipeline_stall_seconds")
         # time spent blocked inside query_fn.complete — for routed
         # fan-outs this includes fold + escalation waves, the number that
         # explains a long-held pipeline slot
-        self.complete_hist = (timers.hist("batch_complete_seconds")
-                              if timers is not None else LatencyHistogram())
+        self.complete_hist = self._timers.hist("batch_complete_seconds")
         self._workers: list[threading.Thread] = []
         if self.pipelined:
             self._inflight: queue.Queue = queue.Queue()
@@ -201,7 +215,7 @@ class DynamicBatcher:
     # ------------------------------------------------------------------ submit
 
     def submit(self, queries: np.ndarray, timeout_s: float | None = None,
-               plan=None, tenant: str | None = None):
+               plan=None, tenant: str | None = None, req: int | None = None):
         """Block until the batch containing ``queries`` executes; returns
         ``(dists, neighbors)`` or raises the request's error. ``plan``
         (serve/recall.py RecallPlan, None = exact) rides the request and
@@ -216,13 +230,15 @@ class DynamicBatcher:
         (byte-identical, zero device work), a duplicate of an in-flight
         row JOINs its owner's entry, and the remaining rows dispatch as
         this request's own sub-batch — seeded with certified init radii
-        on the exact tier. An all-hit request never touches the queue."""
+        on the exact tier. An all-hit request never touches the queue.
+        ``req`` is the caller's request id, carried for the trace."""
         # normalize to [n, dim] rows (flat inputs carry n*dim floats — the
         # legacy direct-caller contract, now D-generic via self.dim)
         queries = np.asarray(queries, np.float32).reshape(-1, self.dim)
         qc = self.qcache
         if qc is None or len(queries) == 0:
-            return self._submit_rows(queries, timeout_s, plan, tenant, None)
+            return self._submit_rows(queries, timeout_s, plan, tenant, None,
+                                     req)
         n = len(queries)
         plan_token = None if plan is None else plan.batch_key()
         actions = qc.begin(queries, plan_token, tenant)
@@ -234,7 +250,7 @@ class DynamicBatcher:
                 sub_q = queries[own_idx] if len(own_idx) < n else queries
                 seeds = qc.seed_for(sub_q, tenant) if plan is None else None
                 outs = self._submit_rows(sub_q, timeout_s, plan, tenant,
-                                         seeds)
+                                         seeds, req)
                 # publish BEFORE waiting on other owners' entries: owners
                 # that publish before they park can never deadlock
                 qc.publish(owned_keys, outs, sub_q, plan_token, tenant)
@@ -263,7 +279,7 @@ class DynamicBatcher:
                     rows[i] = a[1].result
         if retry:
             outs = self._submit_rows(queries[retry], timeout_s, plan,
-                                     tenant, None)
+                                     tenant, None, req)
             for j, i in enumerate(retry):
                 rows[i] = tuple(a[j] for a in outs)
         for i, a in enumerate(actions):
@@ -274,13 +290,13 @@ class DynamicBatcher:
 
     def _submit_rows(self, queries: np.ndarray,
                      timeout_s: float | None, plan, tenant,
-                     seeds: np.ndarray | None):
+                     seeds: np.ndarray | None, req_id: int | None = None):
         """Enqueue one device sub-batch and block for its result — the
         pre-cache submit path, verbatim."""
         now = time.monotonic()
         req = _Request(queries=queries, enqueued=now,
                        deadline=(now + timeout_s) if timeout_s else None,
-                       plan=plan, tenant=tenant, seeds=seeds)
+                       plan=plan, tenant=tenant, seeds=seeds, req=req_id)
         with self._cond:
             if self._shutdown:
                 raise RuntimeError("batcher is shut down")
@@ -313,6 +329,10 @@ class DynamicBatcher:
         BEFORE calling this, so while the pipe is FULL nothing is popped at
         all and late arrivals coalesce into the stalled batch instead of
         queueing behind it.
+
+        Each popped request's queue wait is recorded as ``serve.queue`` and
+        stamped with the batch id; the condition waits are
+        ``serve.batcher.wait`` spans.
         """
         with self._cond:
             while True:
@@ -329,9 +349,11 @@ class DynamicBatcher:
                             or (now >= flush_at and busy_ok)
                             or self._shutdown):
                         break
-                    self._cond.wait((flush_at - now) if busy_ok else None)
+                    with self._timers.phase("serve.batcher.wait"):
+                        self._cond.wait((flush_at - now) if busy_ok else None)
                 else:
-                    self._cond.wait()
+                    with self._timers.phase("serve.batcher.wait"):
+                        self._cond.wait()
             # pop whole requests while they fit; a single over-wide request
             # (> max_batch rows) was rejected upstream by admission sizing,
             # but guard anyway by always taking at least one. Plan-keyed
@@ -350,13 +372,18 @@ class DynamicBatcher:
                 rows += r.rows
             self._queued_rows -= rows
             self.batches += 1
+            batch_id = self.batches
             if batch[0].plan is not None:
                 self.batches_approx += 1
             if rows >= self.max_batch:
                 self.flush_full += 1
             else:
                 self.flush_deadline += 1
-            return batch
+        now = time.monotonic()
+        for r in batch:
+            r.batch = batch_id
+            self._timers.record("serve.queue", now - r.enqueued)
+        return batch
 
     def _split_expired(self, batch: list[_Request]) -> list[_Request]:
         """Fail deadline-expired requests now; return the live remainder."""
@@ -373,18 +400,19 @@ class DynamicBatcher:
                 live.append(r)
         return live
 
-    @staticmethod
-    def _deliver(live: list[_Request], outs: tuple) -> None:
+    def _deliver(self, live: list[_Request], outs: tuple) -> None:
         """Offset-demux every array of the result tuple per request. The
         tuple is ``(dists, neighbors)`` for engines and the replicate pod,
         ``(dists, neighbors, exact)`` for routed fan-outs (the per-row
         exactness mask under degraded serving) — the demux is shape-generic
         so a new result column never touches this code again."""
-        off = 0
-        for r in live:
-            r.result = tuple(a[off:off + r.rows] for a in outs)
-            off += r.rows
-            r.done.set()
+        reqs = ",".join(str(r.req) for r in live if r.req is not None)
+        with self._timers.phase("serve.demux", reqs=reqs):
+            off = 0
+            for r in live:
+                r.result = tuple(a[off:off + r.rows] for a in outs)
+                off += r.rows
+                r.done.set()
 
     @staticmethod
     def _fail(live: list[_Request], err: Exception) -> None:
@@ -416,7 +444,6 @@ class DynamicBatcher:
             if not live:
                 continue
             try:
-                t0 = time.perf_counter()
                 merged = (live[0].queries if len(live) == 1 else
                           np.concatenate([r.queries for r in live]))
                 # exact single-index requests call the legacy single-arg
@@ -427,17 +454,15 @@ class DynamicBatcher:
                 seeds = self._merged_seeds(live)
                 if seeds is not None:
                     kw["seed_radius"] = seeds
-                if tenant is not None:
-                    outs = self._query_fn(merged, plan=plan, tenant=tenant,
-                                          **kw)
-                elif plan is None:
-                    outs = self._query_fn(merged, **kw)
-                else:
-                    outs = self._query_fn(merged, plan=plan, **kw)
-                if self._timers is not None:
-                    self._timers.hist("batch_exec_seconds").record(
-                        time.perf_counter() - t0)
-                self._deliver(live, outs)
+                with span_tags(batch=live[0].batch):
+                    if tenant is not None:
+                        outs = self._query_fn(merged, plan=plan,
+                                              tenant=tenant, **kw)
+                    elif plan is None:
+                        outs = self._query_fn(merged, **kw)
+                    else:
+                        outs = self._query_fn(merged, plan=plan, **kw)
+                    self._deliver(live, outs)
                 with self._cond:
                     self.rows_served += len(merged)
                     if plan is not None:
@@ -454,7 +479,8 @@ class DynamicBatcher:
             while not self._queue:
                 if self._shutdown:
                     return False
-                self._cond.wait()
+                with self._timers.phase("serve.batcher.wait"):
+                    self._cond.wait()
             return True
 
     def _run_dispatch(self):
@@ -479,7 +505,8 @@ class DynamicBatcher:
                 return
             if not self._slots.acquire(blocking=False):
                 t0 = time.perf_counter()
-                self._slots.acquire()
+                with self._timers.phase("serve.batcher.stall"):
+                    self._slots.acquire()
                 stall = time.perf_counter() - t0
                 self.stall_hist.record(stall)
                 with self._cond:
@@ -500,22 +527,22 @@ class DynamicBatcher:
                 self._inflight_batches += 1
                 self._inflight_rows += len(merged)
                 inflight = self._inflight_batches
-            if self._timers is not None:
-                self._timers.gauge("pipeline_inflight_batches", inflight)
+            self._timers.gauge("pipeline_inflight_batches", inflight)
             try:
-                t0 = time.perf_counter()
                 plan, tenant = live[0].plan, live[0].tenant
                 kw = {}
                 seeds = self._merged_seeds(live)
                 if seeds is not None:
                     kw["seed_radius"] = seeds
-                if tenant is not None:
-                    handle = self._query_fn.dispatch(merged, plan=plan,
-                                                     tenant=tenant, **kw)
-                elif plan is None:
-                    handle = self._query_fn.dispatch(merged, **kw)
-                else:
-                    handle = self._query_fn.dispatch(merged, plan=plan, **kw)
+                with span_tags(batch=live[0].batch):
+                    if tenant is not None:
+                        handle = self._query_fn.dispatch(
+                            merged, plan=plan, tenant=tenant, **kw)
+                    elif plan is None:
+                        handle = self._query_fn.dispatch(merged, **kw)
+                    else:
+                        handle = self._query_fn.dispatch(merged, plan=plan,
+                                                         **kw)
             except Exception as e:  # noqa: BLE001 - delivered per request
                 self._fail(live, e)
                 with self._cond:
@@ -523,11 +550,10 @@ class DynamicBatcher:
                     self._inflight_rows -= len(merged)
                     inflight = self._inflight_batches
                     self._cond.notify_all()
-                if self._timers is not None:
-                    self._timers.gauge("pipeline_inflight_batches", inflight)
+                self._timers.gauge("pipeline_inflight_batches", inflight)
                 self._slots.release()
                 continue
-            self._inflight.put((live, len(merged), handle, t0))
+            self._inflight.put((live, len(merged), handle))
             self._announce_prefetch()
 
     def _announce_prefetch(self):
@@ -572,15 +598,13 @@ class DynamicBatcher:
             item = self._inflight.get()
             if item is None:
                 return
-            live, rows, handle, t0 = item
+            live, rows, handle = item
             try:
-                tc = time.perf_counter()
-                outs = self._query_fn.complete(handle)
-                self.complete_hist.record(time.perf_counter() - tc)
-                if self._timers is not None:
-                    self._timers.hist("batch_exec_seconds").record(
-                        time.perf_counter() - t0)
-                self._deliver(live, outs)
+                with span_tags(batch=live[0].batch):
+                    tc = time.perf_counter()
+                    outs = self._query_fn.complete(handle)
+                    self.complete_hist.record(time.perf_counter() - tc)
+                    self._deliver(live, outs)
                 with self._cond:
                     self.rows_served += rows
                     if live[0].plan is not None:
@@ -595,8 +619,7 @@ class DynamicBatcher:
                     # wake a dispatch worker parked on batch-while-busy: the
                     # device freed a slot, so a deadline flush is allowed now
                     self._cond.notify_all()
-                if self._timers is not None:
-                    self._timers.gauge("pipeline_inflight_batches", inflight)
+                self._timers.gauge("pipeline_inflight_batches", inflight)
                 self._slots.release()
 
     # ------------------------------------------------------------------- admin

@@ -83,7 +83,10 @@ from mpi_cuda_largescaleknn_tpu.models.sharding import (
     slab_aabbs,
     slab_bounds,
 )
-from mpi_cuda_largescaleknn_tpu.obs.timers import PhaseTimers
+from mpi_cuda_largescaleknn_tpu.obs.timers import (
+    PhaseTimers,
+    current_span_tags,
+)
 from mpi_cuda_largescaleknn_tpu.utils.math import next_pow2
 
 
@@ -914,46 +917,56 @@ class ResidentKnnEngine:
             return _InFlightBatch(queries, 0, 0, name,
                                   self.merge_mode, None, time.perf_counter(),
                                   plan=plan)
-        qpad = self.bucket_for(n)
-        perm = None
-        if self.sort_queries and n > 1:
-            with self.timers.phase("morton_sort"):
-                perm = morton_argsort(queries, self._index_lo,
-                                      self._index_hi)
-        staged = queries if perm is None else queries[perm]
-        # per-row init radii: max_radius everywhere (pad rows included),
-        # seeded rows clamped below it. The seed vector rides the SAME
-        # Morton permutation as the queries, so staged row i keeps the
-        # radius of the query it carries.
-        r = np.full(qpad, self.max_radius, np.float32)
-        if seed_radius is not None:
-            sr = np.asarray(seed_radius, np.float32).reshape(-1)
-            if len(sr) != n:
-                raise ValueError(
-                    f"seed_radius has {len(sr)} rows for {n} queries")
-            sr = np.minimum(sr, np.float32(self.max_radius))
-            r[:n] = sr if perm is None else sr[perm]
-            self.timers.count(
-                "seeded_rows", int(np.sum(sr < self.max_radius)))
-        with self._lock:
-            exe = self._get_executable(qpad, plan=plan)
-            with self._meta_lock:
-                # consistent with the key _get_executable compiled under:
-                # degrade() needs _lock, which this region holds
-                engine_name = self.engine_name
-            args = self._resident_args(engine_name)
-            q = np.full((qpad, self.dim), PAD_SENTINEL, np.float32)
-            q[:n] = staged
-            t0 = time.perf_counter()
-            q_dev = self._stage_replicated(q)
-            r_dev = self._stage_replicated(r)
-            fut = self._launch.submit(exe, *args, q_dev, r_dev)
-            possible = self._tiles_possible(engine_name, qpad)
+        with self.timers.phase("serve.engine.stage"):
+            qpad = self.bucket_for(n)
+            perm = None
+            if self.sort_queries and n > 1:
+                with self.timers.phase("morton_sort",
+                                       span="serve.engine.morton_sort"):
+                    perm = morton_argsort(queries, self._index_lo,
+                                          self._index_hi)
+            staged = queries if perm is None else queries[perm]
+            # per-row init radii: max_radius everywhere (pad rows included),
+            # seeded rows clamped below it. The seed vector rides the SAME
+            # Morton permutation as the queries, so staged row i keeps the
+            # radius of the query it carries.
+            r = np.full(qpad, self.max_radius, np.float32)
+            if seed_radius is not None:
+                sr = np.asarray(seed_radius, np.float32).reshape(-1)
+                if len(sr) != n:
+                    raise ValueError(
+                        f"seed_radius has {len(sr)} rows for {n} queries")
+                sr = np.minimum(sr, np.float32(self.max_radius))
+                r[:n] = sr if perm is None else sr[perm]
+                self.timers.count(
+                    "seeded_rows", int(np.sum(sr < self.max_radius)))
+            with self._lock:
+                exe = self._get_executable(qpad, plan=plan)
+                with self._meta_lock:
+                    # consistent with the key _get_executable compiled under:
+                    # degrade() needs _lock, which this region holds
+                    engine_name = self.engine_name
+                args = self._resident_args(engine_name)
+                q = np.full((qpad, self.dim), PAD_SENTINEL, np.float32)
+                q[:n] = staged
+                t0 = time.perf_counter()
+                q_dev = self._stage_replicated(q)
+                r_dev = self._stage_replicated(r)
+                fut = self._launch.submit(self._launched, current_span_tags(),
+                                          exe, *args, q_dev, r_dev)
+                possible = self._tiles_possible(engine_name, qpad)
         if plan is not None:
             self.timers.count("approx_batches")
         return _InFlightBatch(queries, n, qpad, engine_name,
                               self.merge_mode, fut, t0, perm=perm,
                               tiles_possible=possible, plan=plan)
+
+    def _launched(self, tags: dict, exe, *args):
+        """The executable call, on a launch thread, as the span
+        ``serve.engine.launch`` tagged like the ``dispatch`` that staged
+        it."""
+        with self.timers.phase("serve.engine.launch", **tags):
+            return exe(*args)
 
     def complete(self, batch: _InFlightBatch):
         """Block on a dispatched batch and finish its cross-shard top-k.
@@ -986,21 +999,17 @@ class ResidentKnnEngine:
             raise RuntimeError(
                 "emit='candidates' engines return full candidate rows — "
                 "use complete_candidates (the routed front end's fold)")
-        a, b, t = batch.fut.result()
-        a = np.asarray(a)
-        b = np.asarray(b)
-        self.timers.hist("engine_batch_seconds").record(
-            time.perf_counter() - batch.t0)
+        a, b, tiles = self._fetch(batch)
         # fetch accounting covers RESULT bytes only (the PR-3 merge
         # placement contract); the [R] i32 tile counter is observability,
         # not payload
         self.timers.count("fetch_bytes", a.nbytes + b.nbytes)
         self.timers.count("result_rows", batch.n)
-        self._count_tiles(self._tiles_fetch(t), batch.tiles_possible)
+        self._count_tiles(tiles, batch.tiles_possible)
         if batch.merge_mode == "device":
             dists, nbrs = a, b  # final already: [qpad], [qpad, k]
         else:
-            with self.timers.phase("host_merge"):
+            with self.timers.phase("host_merge", span="serve.engine.merge"):
                 dists, nbrs = _merge_shard_candidates(
                     a, b, self.num_shards, batch.qpad, self.k)
         dists, nbrs = dists[:batch.n], nbrs[:batch.n]
@@ -1015,6 +1024,19 @@ class ResidentKnnEngine:
             out_n[batch.perm] = nbrs
             dists, nbrs = out_d, out_n
         return dists, nbrs
+
+    def _fetch(self, batch: _InFlightBatch):
+        """Wait for a batch's program and fetch its two result arrays and
+        its executed-tile count (the span ``serve.engine.wait``); records
+        ``engine_batch_seconds``."""
+        with self.timers.phase("serve.engine.wait"):
+            a, b, t = batch.fut.result()
+            a = np.asarray(a)
+            b = np.asarray(b)
+            self.timers.hist("engine_batch_seconds").record(
+                time.perf_counter() - batch.t0)
+            tiles = self._tiles_fetch(t)
+        return a, b, tiles
 
     def complete_candidates(self, batch: _InFlightBatch):
         """Routed-host ``complete``: block on a dispatched batch and return
@@ -1042,18 +1064,14 @@ class ResidentKnnEngine:
                 "engine was built with emit='final': its device-merge "
                 "programs emit kth distances, not candidate rows — "
                 "construct the engine with emit='candidates'")
-        a, b, t = batch.fut.result()
-        a = np.asarray(a)
-        b = np.asarray(b)
-        self.timers.hist("engine_batch_seconds").record(
-            time.perf_counter() - batch.t0)
+        a, b, tiles = self._fetch(batch)
         self.timers.count("fetch_bytes", a.nbytes + b.nbytes)
         self.timers.count("result_rows", batch.n)
-        self._count_tiles(self._tiles_fetch(t), batch.tiles_possible)
+        self._count_tiles(tiles, batch.tiles_possible)
         if batch.merge_mode == "device":
             d2, idx = a, b  # already the merged [qpad, k] candidate rows
         else:
-            with self.timers.phase("host_merge"):
+            with self.timers.phase("host_merge", span="serve.engine.merge"):
                 d2, idx = _merge_shard_candidates(
                     a, b, self.num_shards, batch.qpad, self.k, full=True)
         d2, idx = d2[:batch.n], idx[:batch.n]
@@ -1095,17 +1113,19 @@ class ResidentKnnEngine:
         if batch.n == 0:
             return (np.zeros(0, np.int32), np.zeros(0, np.float32),
                     np.zeros((0, self.k), np.int32))
-        a, b, t = batch.fut.result()
-        self.timers.hist("engine_batch_seconds").record(
-            time.perf_counter() - batch.t0)
+        with self.timers.phase("serve.engine.wait"):
+            a, b, t = batch.fut.result()
+            self.timers.hist("engine_batch_seconds").record(
+                time.perf_counter() - batch.t0)
+            nbrs_by_row = {int(sh.index[0].start): np.asarray(sh.data)
+                           for sh in b.addressable_shards}
+            dists_by_row = [(int(sh.index[0].start), np.asarray(sh.data))
+                            for sh in a.addressable_shards]
+            tiles = self._tiles_fetch(t)
         rp = batch.qpad // self.num_shards
         rows_l, d_l, n_l = [], [], []
         fetched = 0
-        nbrs_by_row = {int(sh.index[0].start): np.asarray(sh.data)
-                       for sh in b.addressable_shards}
-        for sh in a.addressable_shards:
-            lo = int(sh.index[0].start)
-            d = np.asarray(sh.data)
+        for lo, d in dists_by_row:
             nb = nbrs_by_row[lo]
             fetched += d.nbytes + nb.nbytes
             staged = np.arange(lo, lo + rp)
@@ -1118,7 +1138,7 @@ class ResidentKnnEngine:
             d_l.append(d[real])
             n_l.append(nb[real])
         self.timers.count("fetch_bytes", fetched)
-        self._count_tiles(self._tiles_fetch(t), batch.tiles_possible)
+        self._count_tiles(tiles, batch.tiles_possible)
         if not rows_l:
             return (np.zeros(0, np.int32), np.zeros(0, np.float32),
                     np.zeros((0, self.k), np.int32))

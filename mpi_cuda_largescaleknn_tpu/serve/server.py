@@ -24,10 +24,18 @@ servers are byte-identical to the pre-tenancy wire.
 Error mapping: queue full -> 429 + Retry-After (admission backpressure),
 deadline -> 504, batch wider than max_batch -> 413, bad input -> 400.
 /metrics is Prometheus text fed by obs/timers.py's LatencyHistogram.
+
+Spans (obs/timers.py, on the engine's timers; in /stats under
+``engine.timers`` and, while a ``jax.profiler`` trace runs, as host events):
+each POST /knn gets a request id ``req`` and the span ``serve.http`` from
+parse to response written, with ``serve.http.decode`` (the body parse),
+``serve.submit`` (the batcher: query cache, queue, engine, demux) and
+``serve.http.encode`` (serializing and sending the answer) inside it.
 """
 
 from __future__ import annotations
 
+import itertools
 import json
 import threading
 import time
@@ -37,7 +45,7 @@ from urllib.parse import parse_qs, urlparse
 import numpy as np
 
 from mpi_cuda_largescaleknn_tpu.analysis import guarded_by
-from mpi_cuda_largescaleknn_tpu.obs.timers import LatencyHistogram
+from mpi_cuda_largescaleknn_tpu.obs.timers import LatencyHistogram, span_tags
 from mpi_cuda_largescaleknn_tpu.serve.admission import (
     AdmissionController,
     DeadlineExceeded,
@@ -446,6 +454,8 @@ class KnnServer(ThreadingHTTPServer):
             # pipeline can hand it (its async-program-queue stand-in)
             engine.set_launch_workers(pipeline_depth)
         self.metrics = ServingMetrics()
+        #: request ids for the trace (``next`` on a count is atomic)
+        self.request_ids = itertools.count(1)
         self.ready = False
         self.verbose = verbose
         self._loop_entered = False
@@ -718,10 +728,18 @@ class _Handler(JsonHttpHandler):
             return
         if self._apply_fault("/knn"):
             return
+        req = next(srv.request_ids)
+        timers = srv.engine.timers
+        with span_tags(req=req), timers.phase("serve.http"):
+            self._knn(srv, path_tenant, req, timers)
+
+    def _knn(self, srv: KnnServer, path_tenant: str | None, req: int,
+             timers) -> None:
         t0 = time.perf_counter()
         try:
-            q, want_nbrs, timeout_s, recall, tenant, binary = (
-                self._parse_body())
+            with timers.phase("serve.http.decode"):
+                q, want_nbrs, timeout_s, recall, tenant, binary = (
+                    self._parse_body())
         except (ValueError, json.JSONDecodeError) as e:
             srv.metrics.inc("knn_requests_total")
             srv.metrics.inc("knn_badrequest_total")
@@ -766,9 +784,10 @@ class _Handler(JsonHttpHandler):
             admitted = (srv.quotas.admitted_rows(name, n)
                         if srv.quotas is not None
                         else srv.admission.admitted_rows(n))
-            with admitted:
+            with admitted, timers.phase("serve.submit"):
                 dists, nbrs = srv.batcher.submit(q, timeout_s=timeout_s,
-                                                 plan=plan, tenant=name)
+                                                 plan=plan, tenant=name,
+                                                 req=req)
         except OverloadError as e:
             srv.metrics.inc("knn_overload_total", tenant=name)
             self._send_json(429, {"error": str(e)},
@@ -795,15 +814,16 @@ class _Handler(JsonHttpHandler):
         if name is not None:
             fields = dict(fields, tenant=name)
             hdrs = list(hdrs) + [("X-Knn-Tenant", name)]
-        if binary:
-            self._send(200, np.asarray(dists, "<f4").tobytes(),
-                       "application/octet-stream", extra=hdrs)
-        else:
-            out = {"dists": np.asarray(dists, np.float64).tolist()}
-            if want_nbrs:
-                out["neighbors"] = np.asarray(nbrs).tolist()
-            out.update(fields)
-            self._send_json(200, out)
+        with timers.phase("serve.http.encode"):
+            if binary:
+                self._send(200, np.asarray(dists, "<f4").tobytes(),
+                           "application/octet-stream", extra=hdrs)
+            else:
+                out = {"dists": np.asarray(dists, np.float64).tolist()}
+                if want_nbrs:
+                    out["neighbors"] = np.asarray(nbrs).tolist()
+                out.update(fields)
+                self._send_json(200, out)
 
 
 def build_server(engine, host: str = "127.0.0.1", port: int = 8080,
