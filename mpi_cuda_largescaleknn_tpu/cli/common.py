@@ -49,7 +49,8 @@ TPU-side options (no reference analogue):
                     trace/lower/load and enqueue, ring.wait: the device)
   --timings         print phase timings as JSON to stderr: calls and
                     seconds per phase (shard_and_pad, ring, extract) and
-                    per ring span (ring.stage ... ring.wait)
+                    per ring span (ring.stage ... ring.wait), and the
+                    ring.program_builds counter
   --checkpoint-dir D  snapshot exchange state between rounds (both
                     pipelines); an interrupted run relaunched with the same
                     args resumes at the lost round

@@ -616,45 +616,22 @@ def _ring_stats(engine: str, tiles_total: int, bucket_size: int,
             "tiles": int(tiles_total), "flops_per_pair": 8}
 
 
-def ring_knn(points_sharded: jnp.ndarray, ids_sharded: jnp.ndarray, k: int,
-             mesh, *, max_radius: float = jnp.inf, engine: str = "auto",
-             query_tile: int = 2048, point_tile: int = 2048,
-             bucket_size: int = 0, point_group: int = 0,
-             score_dtype: str = "f32",
-             return_candidates: bool = False,
-             return_stats: bool = False,
-             timers: PhaseTimers | None = None):
-    """Run the full R-round ring on a 1-D mesh (fused ``lax.fori_loop``).
-
-    Args:
-      points_sharded: f32[R*Npad, 3], shard-major (device i owns rows
-        [i*Npad, (i+1)*Npad)), sentinel-padded. Device i's rows serve as both
-        its tree shard and its stationary queries (the reference uploads the
-        same slab twice — unorderedDataVariant.cu:159-167).
-      ids_sharded: i32[R*Npad] global point ids (-1 for padding) that travel
-        with the rotating shards so candidate lists can report neighbor
-        identities (the reference computes these but discards them).
-      k / max_radius: the `-k` / `-r` CLI parameters.
-      timers: the caller's ``PhaseTimers``, which get the spans
-        ``ring.stage`` (upload), ``ring.partition``, ``ring.launch`` (the
-        fused program's call: trace, lower, load or compile, enqueue) and
-        ``ring.wait`` (the tile-count fetch, which waits for the device).
-
-    Returns:
-      f32[R*Npad] k-th-NN distances in the same shard-major order (inf for
-      padding rows), plus the CandidateState if ``return_candidates``.
-    """
-    timers = timers if timers is not None else PhaseTimers()
-    engine = resolve_engine(engine)
-    bucket_size = resolve_bucket_size(bucket_size, engine)
-    num_shards = mesh.shape[AXIS]
-    total_rounds = ring_total_rounds(num_shards)
-    npad_local = points_sharded.shape[0] // num_shards
-    point_group = _effective_group(point_group, npad_local, bucket_size, engine)
+# bounded like _partition_smaps: tests build many meshes and configurations
+@lru_cache(maxsize=32)
+def _ring_program(mesh, k, max_radius, engine, query_tile, point_tile,
+                  bucket_size, num_shards, point_group, score_dtype):
+    """(program, kernel, partitioned) — ``ring_knn``'s fused program for
+    one configuration, built once: a fresh closure per call would miss
+    jit's cache (keyed by function identity) and trace and lower the whole
+    ring again on every job. The key is everything the program captures,
+    with every ``auto`` already resolved; the point count is not in it, as
+    jit itself retraces once per new shape. ``partitioned``: the program
+    takes the hoisted partition as a third argument (tiled engines)."""
     init_fn, round_fn, final_fn, _sif, _qif, init_from_q, _qfq, kernel = \
         _make_ring_fns(k, max_radius, engine, query_tile, point_tile,
                        bucket_size, num_shards, warm_start=True,
                        point_group=point_group, score_dtype=score_dtype)
+    total_rounds = ring_total_rounds(num_shards)
 
     def body(pts_local, ids_local, q_local=None):
         if q_local is not None:
@@ -688,19 +665,66 @@ def ring_knn(points_sharded: jnp.ndarray, ids_sharded: jnp.ndarray, k: int,
     # varying operands, which trips shard_map's vma checker (JAX's own
     # guidance: pass check_vma=False); XLA engines keep the strict typing
     check_vma = not engine.startswith("pallas")
-    n_args = 3 if init_from_q is not None else 2
-    mapped = jax.jit(jax.shard_map(
+    partitioned = init_from_q is not None
+    n_args = 3 if partitioned else 2
+    program = jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(shard_spec,) * n_args,
         out_specs=(shard_spec, shard_spec, shard_spec, shard_spec),
         check_vma=check_vma))
+    return program, kernel, partitioned
 
-    sharding = NamedSharding(mesh, shard_spec)
+
+def ring_knn(points_sharded: jnp.ndarray, ids_sharded: jnp.ndarray, k: int,
+             mesh, *, max_radius: float = jnp.inf, engine: str = "auto",
+             query_tile: int = 2048, point_tile: int = 2048,
+             bucket_size: int = 0, point_group: int = 0,
+             score_dtype: str = "f32",
+             return_candidates: bool = False,
+             return_stats: bool = False,
+             timers: PhaseTimers | None = None):
+    """Run the full R-round ring on a 1-D mesh (fused ``lax.fori_loop``).
+
+    Args:
+      points_sharded: f32[R*Npad, 3], shard-major (device i owns rows
+        [i*Npad, (i+1)*Npad)), sentinel-padded. Device i's rows serve as both
+        its tree shard and its stationary queries (the reference uploads the
+        same slab twice — unorderedDataVariant.cu:159-167).
+      ids_sharded: i32[R*Npad] global point ids (-1 for padding) that travel
+        with the rotating shards so candidate lists can report neighbor
+        identities (the reference computes these but discards them).
+      k / max_radius: the `-k` / `-r` CLI parameters.
+      timers: the caller's ``PhaseTimers``, which get the spans
+        ``ring.stage`` (upload), ``ring.partition``, ``ring.launch`` (the
+        fused program's call: the first call for a configuration or shape
+        also traces, lowers and loads or compiles it; every later one only
+        enqueues) and ``ring.wait`` (the tile-count fetch, which waits for
+        the device), and the counter ``ring.program_builds`` (one per
+        configuration the process had not built before).
+
+    Returns:
+      f32[R*Npad] k-th-NN distances in the same shard-major order (inf for
+      padding rows), plus the CandidateState if ``return_candidates``.
+    """
+    timers = timers if timers is not None else PhaseTimers()
+    engine = resolve_engine(engine)
+    bucket_size = resolve_bucket_size(bucket_size, engine)
+    num_shards = mesh.shape[AXIS]
+    npad_local = points_sharded.shape[0] // num_shards
+    point_group = _effective_group(point_group, npad_local, bucket_size, engine)
+    builds = _ring_program.cache_info().misses
+    mapped, kernel, partitioned = _ring_program(
+        mesh, k, float(max_radius), engine, query_tile, point_tile,
+        bucket_size, num_shards, point_group, score_dtype)
+    if _ring_program.cache_info().misses != builds:
+        timers.count("ring.program_builds")
+
+    sharding = NamedSharding(mesh, P(AXIS))
     with timers.phase("ring.stage"):
         points_sharded = jax.device_put(points_sharded, sharding)
         ids_sharded = jax.device_put(ids_sharded, sharding)
     args = (points_sharded, ids_sharded)
-    if init_from_q is not None:
+    if partitioned:
         # tiled path: the log2(B) partition sort passes compile ONCE outside
         # the fused program instead of once per level inside it
         with timers.phase("ring.partition"):

@@ -120,8 +120,15 @@ def test_stats_engine_is_observed_not_resolved(monkeypatch):
 
     real = ring._tiled_engine_fn
     monkeypatch.setattr(ring, "_tiled_engine_fn", lambda e: real("tiled"))
-    model = UnorderedKNN(_cfg(k=4, engine="pallas_tiled"), mesh=get_mesh(2))
-    got = model.run(random_points(160, seed=12))
+    # the fused program is built once per configuration: the patched
+    # dispatcher needs a fresh build, and must not leave it to later tests
+    ring._ring_program.cache_clear()
+    try:
+        model = UnorderedKNN(_cfg(k=4, engine="pallas_tiled"),
+                             mesh=get_mesh(2))
+        got = model.run(random_points(160, seed=12))
+    finally:
+        ring._ring_program.cache_clear()
     assert model.last_stats["engine"] == "tiled"
     pts = random_points(160, seed=12)
     assert_dist_equal(got, kth_nn_dist(pts, pts, 4))
