@@ -1,9 +1,10 @@
 """ring.launch_ms: host time of one self-join job's call of the fused ring
 program, in ms -- the ``ring.launch`` span (``parallel/ring.py``
-``ring_knn``: trace, lower, load or compile, enqueue; the device runs
-after it) over the jobs of the traced slice, from the program's own sum
-of the spans a profiler recorded (``obs/timers.py``
-``traced_spans_snapshot``). Moves ``selfjoin_points_per_s``."""
+``ring_knn``; the device runs after it) over the jobs of the traced
+slice, from the program's own sum of the spans a profiler recorded
+(``obs/timers.py`` ``traced_spans_snapshot``). The first call per
+configuration and shape builds and loads the program, in set-up; every
+call in the window only enqueues it. Moves ``selfjoin_points_per_s``."""
 
 
 def read(run):

@@ -45,9 +45,11 @@ def selfjoin_work(n: int, shards: int, bucket_size: int, point_group: int,
     kernel_tiles = max(0, tiles - warm)
     lanes = s * group
     lanes_pad = -(-lanes // LANE) * LANE
-    # rounds of the ring per job: one query-bucket grid per device-round
-    rounds = shards // 2 + 1
-    q_buckets = shards * b * rounds * jobs
+    # one query-bucket grid per fold, and a job folds every shard once on
+    # every device: R folds in the bidirectional ring's R // 2 + 1 rounds
+    # (two a round, one in round 0 and in the even-R antipodal round)
+    folds = shards
+    q_buckets = shards * b * folds * jobs
     per_qbucket = (s * 3 * F32 + s * F32          # query block + ids
                    + 2 * 2 * s * k * F32          # candidates in and out
                    + 2 * (b // group) * F32)      # SMEM visit schedule

@@ -41,8 +41,10 @@ def parse_args(argv):
 
 def enable_compile_cache() -> None:
     """The program's persistent compile cache, before JAX is imported.
-    Every program is kept however fast it compiled: the self-join builds
-    its ring program anew on each call and must find it in the cache."""
+    Every program is kept however fast it compiled, so that a run's
+    set-up loads every program the window drives from the cache: the
+    self-join's first call per configuration builds and loads its ring
+    program, and every later call only enqueues it."""
     os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
     from mpi_cuda_largescaleknn_tpu.utils.compile_cache import (
         enable_persistent_cache,

@@ -1,18 +1,33 @@
 """Reduction of a JAX profiler trace to device busy time, idle gaps and
 per-operation device time.
 
-The trace is first flattened to plain event records (``load_xplane``), so
-the arithmetic (``reduce``) runs on lists of dicts that a test can build by
-hand or load from a small recorded file.
+The trace is first flattened to plain event records (``flatten``, from the
+planes ``load_xplane`` reads), so the arithmetic (``reduce``) runs on lists
+of dicts that a test can build by hand or load from a small recorded file.
 
-- Device events: planes named ``/device:<X>:<n>``. Busy time per device is
-  the union of its operation intervals (the ``XLA Ops`` line where the
-  plane has one; otherwise every line of the plane) clipped to the window,
-  averaged over the devices that ran anything.
-- Window: the span of the host annotation that brackets the traced slice.
-- Idle gaps: the holes in the union of busy intervals of the first device,
-  each named by the host event that best explains it -- the shortest host
-  event covering at least half of the gap, else the one overlapping it most.
+- Lines read: on a device plane (``/device:<X>:<n>``) the ``XLA Ops`` and
+  ``XLA Modules`` lines; on a host plane (``/host:...``) every line whose
+  name starts with ``python``. Every Python thread's events (JAX's own
+  spans, the program's spans and the benchmark's annotation) sit on one
+  host line named after the executable: ``python``, or ``python3`` when
+  started as the benchmark's command starts it (``python3
+  perfbench/run.py``), or ``python3.11``. The runtime's worker threads
+  log 10^5 events per second on lines of other names (``tf_Compute``,
+  ...) and name no work a user would recognise, so they are skipped
+  unread.
+- Window: the span of the host annotation that brackets the traced slice;
+  without one, the extent of every event read.
+- Devices: busy time per device is the union of its operation intervals
+  (the ``XLA Ops`` line where the plane has one; otherwise its ``XLA
+  Modules`` line) clipped to the window. ``busy_s`` is the mean over the
+  devices that ran anything in the window; ``op_seconds`` sums each
+  operation's device time over every device. So a reader that divides
+  the work of all devices, at one chip's peak, by summed ``op_seconds``
+  reads a per-chip average, as ``busy_s`` is one.
+- Idle gaps: the holes in the union of busy intervals of the first device
+  (by plane name), each named by the host event that best explains it --
+  the shortest host event covering at least half of the gap, else the one
+  overlapping it most.
 """
 
 from __future__ import annotations
@@ -23,30 +38,39 @@ import re
 
 DEVICE_PLANE = re.compile(r"^/device:[A-Z]+:\d+$")
 OPS_LINE = "XLA Ops"
-#: lines worth reading: device operations and programs, and the host's
-#: Python thread (JAX's own spans and the benchmark's annotations). The
-#: runtime's worker threads log 10^5 events per second and name no work
-#: a user would recognise, so they are skipped unread.
+#: lines worth reading (module docstring): device operations and programs,
+#: and the host line of the Python threads, named after the executable
 DEVICE_LINES = (OPS_LINE, "XLA Modules")
-HOST_LINES = ("python",)
+HOST_LINE_PREFIX = "python"
+
+
+def reads_line(plane: str, line: str) -> bool:
+    """Whether the events of ``line`` on ``plane`` are read."""
+    if DEVICE_PLANE.match(plane):
+        return line in DEVICE_LINES
+    return plane.startswith("/host:") and line.startswith(HOST_LINE_PREFIX)
 
 
 def load_xplane(trace_dir: str) -> list[dict]:
-    """Every event of the newest ``.xplane.pb`` under ``trace_dir``."""
+    """The events read from the newest ``.xplane.pb`` under ``trace_dir``."""
     from jax.profiler import ProfileData
 
     paths = sorted(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
                              recursive=True), key=os.path.getmtime)
     if not paths:
         raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
-    data = ProfileData.from_file(paths[-1])
+    return flatten(ProfileData.from_file(paths[-1]).planes)
+
+
+def flatten(planes) -> list[dict]:
+    """Event records of the lines ``reads_line`` keeps. ``planes`` are
+    profiler planes, or anything with their ``name``, ``lines`` and, per
+    line, ``name`` and ``events`` (``name``, ``start_ns``,
+    ``duration_ns``)."""
     events = []
-    for plane in data.planes:
-        device = bool(DEVICE_PLANE.match(plane.name))
-        if not device and not plane.name.startswith("/host:"):
-            continue
+    for plane in planes:
         for line in plane.lines:
-            if line.name not in (DEVICE_LINES if device else HOST_LINES):
+            if not reads_line(plane.name, line.name):
                 continue
             for ev in line.events:
                 events.append({"plane": plane.name, "line": line.name,
