@@ -5,9 +5,11 @@ progress lines only) — this subsystem is the capability the TPU build adds so
 BASELINE numbers can be produced at all. A span (``PhaseTimers.phase``) adds
 its wall-clock to a per-name aggregate and, while a ``jax.profiler`` trace
 is being collected, writes a host event on the profiler's clock, so a
-trace's idle gaps name the host work that left the device waiting.
-Log-bucketed latency histograms (p50/p95/p99) are shared by ``--timings``,
-the serving layer's ``/metrics`` endpoint, and ``tools/loadgen.py``.
+trace's idle gaps name the host work that left the device waiting. Spans
+and counts made while a trace is collected are also summed process-wide
+(``traced_spans_snapshot``, ``traced_counters_snapshot``). Log-bucketed
+latency histograms (p50/p95/p99) are shared by ``--timings``, the serving
+layer's ``/metrics`` endpoint, and ``tools/loadgen.py``.
 
 JAX is never imported here: a span looks for ``jax.profiler`` only once the
 process has imported JAX itself (a process without JAX runs no profiler),
@@ -167,19 +169,31 @@ def _trace_annotation():
     return _annotation
 
 
-# spans that closed while a profiler was collecting, over the whole
-# process: {trace name: [count, seconds]}
+# spans that closed, and counts that were added, while a profiler was
+# collecting, over the whole process: {trace name: [count, seconds]} and
+# {counter: total}
 _traced_lock = threading.Lock()
 _traced: dict[str, list] = {}
+_traced_counts: dict[str, int] = {}
+
+
+def _tracing() -> bool:
+    ann = _trace_annotation()
+    return ann is not None and ann.is_enabled()
 
 
 def _note_traced(name: str, seconds: float) -> None:
-    ann = _trace_annotation()
-    if ann is not None and ann.is_enabled():
+    if _tracing():
         with _traced_lock:
             agg = _traced.setdefault(name, [0, 0.0])
             agg[0] += 1
             agg[1] += seconds
+
+
+def _note_traced_count(name: str, by: int) -> None:
+    if _tracing():
+        with _traced_lock:
+            _traced_counts[name] = _traced_counts.get(name, 0) + by
 
 
 def traced_spans_snapshot() -> dict:
@@ -189,6 +203,15 @@ def traced_spans_snapshot() -> dict:
     reading the trace back."""
     with _traced_lock:
         return {n: (c, s) for n, (c, s) in _traced.items()}
+
+
+def traced_counters_snapshot() -> dict:
+    """``{counter: total}`` of every ``PhaseTimers.count`` of this process
+    made while a ``jax.profiler`` trace was being collected: the counts of
+    the traced slices, summed over every ``PhaseTimers``, as
+    ``traced_spans_snapshot`` sums spans."""
+    with _traced_lock:
+        return dict(_traced_counts)
 
 
 class _Span:
@@ -280,9 +303,11 @@ class PhaseTimers:
     def count(self, name: str, by: int = 1) -> None:
         """Monotonic counter (e.g. ``fetch_bytes``, ``result_rows``) —
         unlike gauges, increments from concurrent completion threads must
-        not lose updates, hence the lock."""
+        not lose updates, hence the lock. While a profiler trace is being
+        collected the count also adds to ``traced_counters_snapshot()``."""
         with self._counter_lock:
             self.counters[name] = self.counters.get(name, 0) + int(by)
+        _note_traced_count(name, int(by))
 
     def counter(self, name: str) -> int:
         with self._counter_lock:

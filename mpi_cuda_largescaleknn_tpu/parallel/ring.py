@@ -341,6 +341,24 @@ def _tiled_engine_fn(engine: str):
                      "'auto' first)")
 
 
+def _rotate_pair(shard_pair, num_shards: int):
+    """One rotation of the bidirectional ring: the forward copy of every
+    leaf moves to the next device and the backward copy to the previous,
+    one ``ppermute`` per leaf and direction (``rotation_bytes``)."""
+    fwd = [(i, (i + 1) % num_shards) for i in range(num_shards)]
+    bwd = [(i, (i - 1) % num_shards) for i in range(num_shards)]
+    f, b = shard_pair
+    return (jax.tree.map(lambda a: jax.lax.ppermute(a, AXIS, fwd), f),
+            jax.tree.map(lambda a: jax.lax.ppermute(a, AXIS, bwd), b))
+
+
+def _duplicate_round(rnd, num_shards: int):
+    """Whether round ``rnd`` delivers one shard in both copies: round 0
+    (the own shard) and, for even R, round R/2 (the antipode). Its
+    backward fold is skipped, so every shard is folded exactly once."""
+    return (rnd == 0) | (2 * rnd == num_shards)
+
+
 def _make_ring_fns(k, max_radius, engine, query_tile, point_tile, bucket_size,
                    num_shards, warm_start=False, point_group=1,
                    score_dtype="f32"):
@@ -388,17 +406,6 @@ def _make_ring_fns(k, max_radius, engine, query_tile, point_tile, bucket_size,
     # cold. Chunked drivers partition queries separately and always stay
     # cold.
     warm_start = warm_start and engine == "pallas_tiled"
-    fwd = [(i, (i + 1) % num_shards) for i in range(num_shards)]
-    bwd = [(i, (i - 1) % num_shards) for i in range(num_shards)]
-
-    def rotate_pair(shard_pair):
-        f, b = shard_pair
-        return (jax.tree.map(lambda a: jax.lax.ppermute(a, AXIS, fwd), f),
-                jax.tree.map(lambda a: jax.lax.ppermute(a, AXIS, bwd), b))
-
-    def is_dup(rnd):
-        # round 0 (own shard twice) and, for even R, round R/2 (antipode)
-        return (rnd == 0) | (2 * rnd == num_shards)
 
     if use_tiled:
         kernel, tiled_update = _tiled_engine_fn(engine)
@@ -424,7 +431,7 @@ def _make_ring_fns(k, max_radius, engine, query_tile, point_tile, bucket_size,
                 heap = warm_start_self(pc, k, max_radius)
             else:
                 _, heap = query_from_q(q)
-            shard = (pc.pts, pc.ids, pc.lower, pc.upper)
+            shard = _resident_shard(pc)
             return q, (shard, shard), heap
 
         def fold_one(q, shard, heap, sskip=None):
@@ -441,7 +448,8 @@ def _make_ring_fns(k, max_radius, engine, query_tile, point_tile, bucket_size,
             # the final round's rotation would be discarded — callers pass
             # rotate=False there (static flag: collectives cannot sit
             # under a traced cond)
-            nxt = rotate_pair(shard_pair) if rotate else shard_pair
+            nxt = (_rotate_pair(shard_pair, num_shards) if rotate
+                   else shard_pair)
             f, b = shard_pair
             # round 0's forward fold is the own shard: with a warm-started
             # heap its self buckets are already folded and must be masked
@@ -455,8 +463,8 @@ def _make_ring_fns(k, max_radius, engine, query_tile, point_tile, bucket_size,
             hd2, hidx, tiles_b = jax.lax.cond(
                 # tiles_f * 0, not a fresh zero: the constant would be
                 # replicated and mismatch fold_b's axis-varying count
-                is_dup(rnd), lambda _: (st.dist2, st.idx, tiles_f * 0),
-                fold_b, None)
+                _duplicate_round(rnd, num_shards),
+                lambda _: (st.dist2, st.idx, tiles_f * 0), fold_b, None)
             return nxt, CandidateState(hd2, hidx), (tiles_f + tiles_b)[None]
 
         def final_fn(q, heap, npad):
@@ -477,18 +485,19 @@ def _make_ring_fns(k, max_radius, engine, query_tile, point_tile, bucket_size,
     else:
         kernel, update = _engine_fn(engine, query_tile, point_tile,
                                     score_dtype)
-        use_tree = engine == "tree"
 
         def query_init_fn(qpts_local, qids_local):
             heap = pvary(init_candidates(qpts_local.shape[0], k, max_radius))
             return qpts_local, heap
 
         def round_fn(queries, shard_pair, heap, rnd, rotate=True):
-            nxt = rotate_pair(shard_pair) if rotate else shard_pair
+            nxt = (_rotate_pair(shard_pair, num_shards) if rotate
+                   else shard_pair)
             f, b = shard_pair
             st = update(heap, queries, f[0], f[1])
             hd2, hidx = jax.lax.cond(
-                is_dup(rnd), lambda _: (st.dist2, st.idx),
+                _duplicate_round(rnd, num_shards),
+                lambda _: (st.dist2, st.idx),
                 lambda _: (lambda s2: (s2.dist2, s2.idx))(
                     update(st, queries, b[0], b[1])), None)
             st = CandidateState(hd2, hidx)
@@ -500,9 +509,7 @@ def _make_ring_fns(k, max_radius, engine, query_tile, point_tile, bucket_size,
             return extract_final_result(heap), heap.dist2, heap.idx
 
         def shard_init_fn(pts_local, ids_local):
-            if use_tree:
-                return build_tree(pts_local, ids_local)
-            return (pts_local, ids_local)
+            return _flat_shard(engine, pts_local, ids_local)
 
         def init_fn(pts_local, ids_local):
             q, heap = query_init_fn(pts_local, ids_local)
@@ -513,6 +520,42 @@ def _make_ring_fns(k, max_radius, engine, query_tile, point_tile, bucket_size,
 
     return (init_fn, round_fn, final_fn, shard_init_fn, query_init_fn,
             init_from_q, query_from_q, kernel)
+
+
+def _resident_shard(pc: BucketedPoints) -> tuple:
+    """The tiled path's rotating shard: a (group-coarsened) partition's
+    points, ids and bucket boxes; ``pos`` is query-side metadata and
+    stays on its device."""
+    return (pc.pts, pc.ids, pc.lower, pc.upper)
+
+
+def _flat_shard(engine: str, pts_local, ids_local):
+    """The flat engines' rotating shard: the slab itself, or its tree."""
+    if engine == "tree":
+        return build_tree(pts_local, ids_local)
+    return (pts_local, ids_local)
+
+
+def rotation_bytes(shard) -> int:
+    """Bytes one device sends in one rotation of the ring: ``_rotate_pair``
+    ppermutes both counter-rotating copies of every leaf of its resident
+    ``shard`` (points, ids and, on the tiled path, the bucket boxes).
+    ``shard`` holds one device's arrays or ``jax.ShapeDtypeStruct``s."""
+    return 2 * sum(math.prod(a.shape) * np.dtype(a.dtype).itemsize
+                   for a in jax.tree.leaves(shard))
+
+
+@lru_cache(maxsize=32)
+def _rotation_bytes_of(engine: str, point_group: int, local) -> int:
+    """``rotation_bytes`` of the shard the ring programs build from one
+    device's inputs ``local`` (``ShapeDtypeStruct``s: the partition on the
+    tiled path, else points and ids), from shapes alone: no device work."""
+    if engine in ("tiled", "pallas_tiled"):
+        shard = jax.eval_shape(
+            lambda q: _resident_shard(coarsen_buckets(q, point_group)), local)
+    else:
+        shard = jax.eval_shape(partial(_flat_shard, engine), *local)
+    return rotation_bytes(shard)
 
 
 def _pair_step_fn(round_fn, rotate=True):
@@ -699,8 +742,11 @@ def ring_knn(points_sharded: jnp.ndarray, ids_sharded: jnp.ndarray, k: int,
         fused program's call: the first call for a configuration or shape
         also traces, lowers and loads or compiles it; every later one only
         enqueues) and ``ring.wait`` (the tile-count fetch, which waits for
-        the device), and the counter ``ring.program_builds`` (one per
-        configuration the process had not built before).
+        the device), and the counters ``ring.program_builds`` (one per
+        configuration the process had not built before) and
+        ``ring.exchange_bytes`` (what every device of the mesh sends in
+        the job's rotations, counted from static shapes; 0 at one shard,
+        and ``stats["exchange_bytes"]``).
 
     Returns:
       f32[R*Npad] k-th-NN distances in the same shard-major order (inf for
@@ -732,19 +778,29 @@ def ring_knn(points_sharded: jnp.ndarray, ids_sharded: jnp.ndarray, k: int,
                                        bucket_size),)
     with timers.phase("ring.launch"):
         dists, hd2, hidx, tiles = mapped(*args)
+    # one device's share of what the program makes its shard from; every
+    # round but the last rotates
+    local = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct((a.shape[0] // num_shards,)
+                                       + a.shape[1:], a.dtype),
+        args[2] if partitioned else args)
+    exchange_bytes = (num_shards * (ring_total_rounds(num_shards) - 1)
+                      * _rotation_bytes_of(engine, point_group, local))
+    timers.count("ring.exchange_bytes", exchange_bytes)
     out = (dists,)
     if return_candidates:
         out += (CandidateState(hd2, hidx),)
     if return_stats:
         with timers.phase("ring.wait"):
             tiles_total = int(np.asarray(tiles).sum())
-        out += (_ring_stats(
+        stats = _ring_stats(
             kernel, tiles_total
             + _warm_tiles(engine, npad_local, bucket_size, num_shards),
             bucket_size,
             num_shards * num_shards * npad_local * npad_local,
             q_rows=npad_local, p_rows=npad_local,
-            point_group=point_group),)
+            point_group=point_group)
+        out += (dict(stats, exchange_bytes=exchange_bytes),)
     return out if len(out) > 1 else out[0]
 
 

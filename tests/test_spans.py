@@ -66,6 +66,19 @@ def _delta(before, after, name):
     return c1 - c0, s1 - s0
 
 
+def _await_spans(timers, before, name, n, timeout_s=10.0):
+    """``timers.spans_snapshot()`` once ``n`` more ``name`` spans than in
+    ``before`` have closed, or at ``timeout_s``. ``serve.http`` closes after
+    the response is written, so a client can hold its answer before the
+    handler thread has closed the span."""
+    deadline = time.monotonic() + timeout_s
+    snap = timers.spans_snapshot()
+    while _delta(before, snap, name)[0] < n and time.monotonic() < deadline:
+        time.sleep(0.01)
+        snap = timers.spans_snapshot()
+    return snap
+
+
 class TestSpanAggregates:
     def test_nested_spans_add_count_and_seconds(self):
         t = PhaseTimers()
@@ -259,6 +272,7 @@ class TestServingSpans:
             for seed in range(3):
                 answers.append(_post(span_server,
                                      random_points(4, seed=100 + seed)))
+            _await_spans(timers, before, "serve.http", 3)
 
         events = _traced(load)
         after = timers.spans_snapshot()
@@ -295,7 +309,7 @@ class TestServingSpans:
         for seed in range(4):
             assert _post(span_server, random_points(3, seed=200 + seed))[0] \
                 == 200
-        after = timers.spans_snapshot()
+        after = _await_spans(timers, before, "serve.http", 4)
         n_http, s_http = _delta(before, after, "serve.http")
         n_sub, s_sub = _delta(before, after, "serve.submit")
         assert n_http == n_sub == 4
