@@ -327,7 +327,8 @@ def knn_update_tiled_pallas(state: CandidateState, q: BucketedPoints,
                             skip_self=None, self_group: int = 1,
                             canonical_ties: bool = False,
                             score_dtype: str = "f32",
-                            point_norms2=None):
+                            point_norms2=None,
+                            decode_ids: bool = True):
     """Drop-in Pallas twin of ``ops.tiled.knn_update_tiled`` (same contract:
     state rows in ``q``'s bucket order; folds every real point of ``p`` in;
     ``with_stats`` additionally returns the i32 count of [S, T] tiles
@@ -373,7 +374,18 @@ def knn_update_tiled_pallas(state: CandidateState, q: BucketedPoints,
     (true of everything this package produces — real ids are ``>= 0``, the
     pad sentinel is ``-1``). Values ``<= -2`` would alias the fold's
     lane-position encoding and decode to unrelated ids
-    (fold_tile_into_candidates)."""
+    (fold_tile_into_candidates).
+
+    ``decode_ids=False`` is for callers that read only distances: the
+    wrapper then skips the position decode (two gathers of every candidate
+    slot) and the returned ``state.idx`` is opaque — entries this call
+    adopted stay the kernel's encoded lane positions (``<= -2``, or under
+    ``score_dtype="bf16"``, whose rescore still needs the positions,
+    non-negative flat positions in this call's resident layout); carried-in
+    entries pass through. The distances are the same bits as with
+    ``decode_ids=True`` (the fold only carries ids), so such a state may be
+    folded again with ``decode_ids=False``; its ids must never be read, and
+    ``canonical_ties`` then orders equal distances by those opaque values."""
     from mpi_cuda_largescaleknn_tpu.ops.distance import (
         mxu_min_dim,
         rescore_width,
@@ -487,11 +499,15 @@ def knn_update_tiled_pallas(state: CandidateState, q: BucketedPoints,
     # starts are real ids (>= -1) and pass through untouched.
     t_pad = p_t.shape[2]
     enc = out_idx.reshape(num_qb, s_q * k_eff)
-    pos = jnp.clip(-2 - enc, 0, p_t.shape[0] * t_pad - 1)
-    flat_pos = jnp.take_along_axis(order, pos // t_pad, axis=1) * t_pad \
-        + pos % t_pad
-    ids_new = jnp.take(pid.reshape(-1), flat_pos, axis=0)
-    out_idx = jnp.where(enc <= -2, ids_new, enc).reshape(out_idx.shape)
+    if decode_ids or use_mxu:
+        pos = jnp.clip(-2 - enc, 0, p_t.shape[0] * t_pad - 1)
+        flat_pos = jnp.take_along_axis(order, pos // t_pad, axis=1) \
+            * t_pad + pos % t_pad
+        # without decode_ids, the rescore's own positions (>= 0) mark its
+        # entries as carried in for any later fold
+        ids_new = (jnp.take(pid.reshape(-1), flat_pos, axis=0)
+                   if decode_ids else flat_pos)
+        out_idx = jnp.where(enc <= -2, ids_new, enc).reshape(out_idx.shape)
     if use_mxu:
         # exact f32 rescore of every entry the fold adopted by approx
         # score: gather the survivor coordinates back through the same

@@ -361,7 +361,7 @@ def _duplicate_round(rnd, num_shards: int):
 
 def _make_ring_fns(k, max_radius, engine, query_tile, point_tile, bucket_size,
                    num_shards, warm_start=False, point_group=1,
-                   score_dtype="f32"):
+                   score_dtype="f32", with_ids=True):
     """(init_fn, round_fn, final_fn, shard_init_fn, query_init_fn,
     init_from_q, query_from_q, kernel) — the per-round pieces every ring
     driver executes, defined once so the fused,
@@ -380,7 +380,7 @@ def _make_ring_fns(k, max_radius, engine, query_tile, point_tile, bucket_size,
       for the pruned tiled engines, 0 for flat engines whose all-pairs count
       is analytic and added by the drivers)
     - final_fn(stationary, heap, npad) -> (dists, hd2, hidx) in input-row
-      order per shard
+      order per shard; ``(dists,)`` alone without ``with_ids``
     - kernel: the engine name _engine_fn/_tiled_engine_fn dispatched — what
       the drivers report as ``stats["engine"]``
 
@@ -395,6 +395,11 @@ def _make_ring_fns(k, max_radius, engine, query_tile, point_tile, bucket_size,
     deliveries (round 0: both copies are the own shard; round R/2 for even
     R: both copies are the antipodal shard) — the backward fold is skipped
     there, keeping every shard folded exactly once.
+
+    ``with_ids=False`` builds distance-only pieces: the Pallas fold skips
+    its neighbour-id decode (``decode_ids``), so the heap's ids become
+    opaque, and final_fn scatters no candidate rows back. The distances
+    are the same bits.
     """
     use_tiled = engine in ("tiled", "pallas_tiled")
     # warm start only applies to self-joins on ONE shared partition (query
@@ -409,6 +414,8 @@ def _make_ring_fns(k, max_radius, engine, query_tile, point_tile, bucket_size,
 
     if use_tiled:
         kernel, tiled_update = _tiled_engine_fn(engine)
+        # keyed on the kernel dispatched: only the Pallas wrapper decodes
+        ids_kw = {} if kernel == "tiled" else {"decode_ids": with_ids}
 
         def query_from_q(q):
             # heap init for an ALREADY-partitioned query side (the drivers
@@ -442,7 +449,7 @@ def _make_ring_fns(k, max_radius, engine, query_tile, point_tile, bucket_size,
                                       shard[1])
             return tiled_update(heap, q, resident, with_stats=True,
                                 skip_self=sskip, self_group=point_group,
-                                score_dtype=score_dtype)
+                                score_dtype=score_dtype, **ids_kw)
 
         def round_fn(q, shard_pair, heap, rnd, rotate=True):
             # the final round's rotation would be discarded — callers pass
@@ -472,6 +479,8 @@ def _make_ring_fns(k, max_radius, engine, query_tile, point_tile, bucket_size,
             bs = (q.num_buckets, q.bucket_size)
             dists = scatter_back(extract_final_result(heap).reshape(bs),
                                  q.pos, npad, fill=jnp.inf)
+            if not with_ids:
+                return (dists,)
             hd2 = scatter_back(heap.dist2.reshape(bs + (kk,)), q.pos, npad,
                                fill=jnp.inf)
             hidx = scatter_back(heap.idx.reshape(bs + (kk,)), q.pos, npad,
@@ -506,6 +515,8 @@ def _make_ring_fns(k, max_radius, engine, query_tile, point_tile, bucket_size,
             return nxt, st, pvary(jnp.zeros((1,), jnp.int32))
 
         def final_fn(_queries, heap, _npad):
+            if not with_ids:
+                return (extract_final_result(heap),)
             return extract_final_result(heap), heap.dist2, heap.idx
 
         def shard_init_fn(pts_local, ids_local):
@@ -662,18 +673,25 @@ def _ring_stats(engine: str, tiles_total: int, bucket_size: int,
 # bounded like _partition_smaps: tests build many meshes and configurations
 @lru_cache(maxsize=32)
 def _ring_program(mesh, k, max_radius, engine, query_tile, point_tile,
-                  bucket_size, num_shards, point_group, score_dtype):
+                  bucket_size, num_shards, point_group, score_dtype,
+                  with_ids):
     """(program, kernel, partitioned) — ``ring_knn``'s fused program for
     one configuration, built once: a fresh closure per call would miss
     jit's cache (keyed by function identity) and trace and lower the whole
     ring again on every job. The key is everything the program captures,
     with every ``auto`` already resolved; the point count is not in it, as
     jit itself retraces once per new shape. ``partitioned``: the program
-    takes the hoisted partition as a third argument (tiled engines)."""
+    takes the hoisted partition as a third argument (tiled engines).
+
+    The program returns ``(dists, hd2, hidx, tiles)`` with ``with_ids``
+    and ``(dists, tiles)`` without: a distance-only program neither
+    decodes neighbour ids after its folds nor scatters candidate rows
+    back (``_make_ring_fns``), and it is a configuration of its own."""
     init_fn, round_fn, final_fn, _sif, _qif, init_from_q, _qfq, kernel = \
         _make_ring_fns(k, max_radius, engine, query_tile, point_tile,
                        bucket_size, num_shards, warm_start=True,
-                       point_group=point_group, score_dtype=score_dtype)
+                       point_group=point_group, score_dtype=score_dtype,
+                       with_ids=with_ids)
     total_rounds = ring_total_rounds(num_shards)
 
     def body(pts_local, ids_local, q_local=None):
@@ -713,7 +731,7 @@ def _ring_program(mesh, k, max_radius, engine, query_tile, point_tile,
     program = jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(shard_spec,) * n_args,
-        out_specs=(shard_spec, shard_spec, shard_spec, shard_spec),
+        out_specs=(shard_spec,) * (4 if with_ids else 2),
         check_vma=check_vma))
     return program, kernel, partitioned
 
@@ -746,7 +764,13 @@ def ring_knn(points_sharded: jnp.ndarray, ids_sharded: jnp.ndarray, k: int,
         configuration the process had not built before) and
         ``ring.exchange_bytes`` (what every device of the mesh sends in
         the job's rotations, counted from static shapes; 0 at one shard,
-        and ``stats["exchange_bytes"]``).
+        and ``stats["exchange_bytes"]``), and ``ring.id_decode_folds``
+        (the folds whose neighbour ids the job decoded: every fold when
+        ``return_candidates``, else 0).
+      return_candidates: also return the final candidate rows. It picks
+        the program: without it the job reads distances only, and its
+        program decodes no ids and scatters no candidate rows
+        (``_ring_program``'s ``with_ids``; ``stats["neighbor_ids"]``).
 
     Returns:
       f32[R*Npad] k-th-NN distances in the same shard-major order (inf for
@@ -761,9 +785,14 @@ def ring_knn(points_sharded: jnp.ndarray, ids_sharded: jnp.ndarray, k: int,
     builds = _ring_program.cache_info().misses
     mapped, kernel, partitioned = _ring_program(
         mesh, k, float(max_radius), engine, query_tile, point_tile,
-        bucket_size, num_shards, point_group, score_dtype)
+        bucket_size, num_shards, point_group, score_dtype,
+        bool(return_candidates))
     if _ring_program.cache_info().misses != builds:
         timers.count("ring.program_builds")
+    total_rounds = ring_total_rounds(num_shards)
+    timers.count("ring.id_decode_folds",
+                 _folds_in_rounds(0, total_rounds, num_shards)
+                 if return_candidates else 0)
 
     sharding = NamedSharding(mesh, P(AXIS))
     with timers.phase("ring.stage"):
@@ -777,19 +806,20 @@ def ring_knn(points_sharded: jnp.ndarray, ids_sharded: jnp.ndarray, k: int,
             args += (partition_sharded(points_sharded, ids_sharded, mesh,
                                        bucket_size),)
     with timers.phase("ring.launch"):
-        dists, hd2, hidx, tiles = mapped(*args)
+        outs = mapped(*args)
+    dists, tiles = outs[0], outs[-1]
     # one device's share of what the program makes its shard from; every
     # round but the last rotates
     local = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct((a.shape[0] // num_shards,)
                                        + a.shape[1:], a.dtype),
         args[2] if partitioned else args)
-    exchange_bytes = (num_shards * (ring_total_rounds(num_shards) - 1)
+    exchange_bytes = (num_shards * (total_rounds - 1)
                       * _rotation_bytes_of(engine, point_group, local))
     timers.count("ring.exchange_bytes", exchange_bytes)
     out = (dists,)
     if return_candidates:
-        out += (CandidateState(hd2, hidx),)
+        out += (CandidateState(*outs[1:3]),)
     if return_stats:
         with timers.phase("ring.wait"):
             tiles_total = int(np.asarray(tiles).sum())
@@ -800,7 +830,8 @@ def ring_knn(points_sharded: jnp.ndarray, ids_sharded: jnp.ndarray, k: int,
             num_shards * num_shards * npad_local * npad_local,
             q_rows=npad_local, p_rows=npad_local,
             point_group=point_group)
-        out += (dict(stats, exchange_bytes=exchange_bytes),)
+        out += (dict(stats, exchange_bytes=exchange_bytes,
+                     neighbor_ids=bool(return_candidates)),)
     return out if len(out) > 1 else out[0]
 
 
