@@ -315,8 +315,8 @@ class PhaseTimers:
 
     def counters_snapshot(self) -> dict:
         """Atomic copy of every counter — for before/after deltas across a
-        measurement window (e.g. serve_smoke's locality bench diffing
-        ``tiles_executed`` over one loadgen run) without racing concurrent
+        measurement window (e.g. perfbench/systems/serve.py diffing
+        ``result_rows`` over the timed window) without racing concurrent
         completion-thread increments between two ``counter()`` reads."""
         with self._counter_lock:
             return dict(self.counters)

@@ -111,9 +111,10 @@ def mxu_min_dim() -> int:
     """Smallest point dimensionality at which ``score_dtype="bf16"``
     actually engages the matmul-form scorer; below it the exact elementwise
     path IS the fast path (at D=3 the MXU would run at 3/128 utilization
-    and the survivor-selection machinery is pure overhead — the CPU-fixture
-    crossover measured at D~16, kernel_compare in BENCH_serve.json), so
-    requesting bf16 there silently scores exactly. ``LSK_MXU_MIN_DIM``
+    and the survivor-selection machinery is pure overhead; 16 is a
+    crossover measured on the CPU, not on the chip), so requesting bf16
+    there silently scores exactly (tests/test_distance.py
+    ``TestShippedMxuThreshold``). ``LSK_MXU_MIN_DIM``
     overrides (trace-time; the parity tests pin it to 1 to exercise the
     MXU machinery at every D)."""
     try:

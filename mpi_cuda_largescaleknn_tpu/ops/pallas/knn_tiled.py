@@ -471,8 +471,8 @@ def knn_update_tiled_pallas(state: CandidateState, q: BucketedPoints,
         # enough lanes per chunk to amortize the loop step (~2048) without
         # blowing the VMEM budget on the [S, V*T] distance tile.
         # LSK_CHUNK_LANES overrides for on-chip tuning — read at TRACE time,
-        # so it must be set before the first run of a process (tpu_tune runs
-        # one fresh subprocess per cell); changing it mid-process is ignored
+        # so it must be set before the first run of a process (a sweep runs
+        # one fresh process per value); changing it mid-process is ignored
         # by the jit cache
         lanes = int(os.environ.get("LSK_CHUNK_LANES", 2048))
         visit_batch = max(1, lanes // p_t.shape[2])

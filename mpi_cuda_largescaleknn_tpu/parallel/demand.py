@@ -31,9 +31,9 @@ all shards within ±r of its own. Round-4 measurement motivated this: with a
 forward-only ring (arrival round of shard s = (me - s) mod R) on
 spatially-sorted partitions, a device's following neighbor (index i+1)
 arrived LAST (round R-1) even though spatial locality makes it needed on
-round one — so the early exit never fired (64 rounds measured vs 33 for
-the reference's best schedule at 64 shards; after this change, 21 —
-benchmarks_report.json). Needed peers cluster around ±max_offset, and
+round one — so the early exit never fired (on the CPU fixture at 64
+shards: 64 rounds vs 33 for the reference's best schedule; after this
+change, 21; not measured on the chip). Needed peers cluster around ±max_offset, and
 counter-rotation reaches offset o in round o: the loop runs at most
 floor(R/2)+1 rounds and the exit fires after max needed offset rounds.
 Total bytes moved are the same (2 trees/round x ~R/2 rounds); per-round

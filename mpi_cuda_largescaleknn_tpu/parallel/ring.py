@@ -215,8 +215,8 @@ def resolve_query_buckets(query_buckets: int, qpad: int, k: int) -> int:
     The result always divides ``qpad`` (both are powers of two) and leaves
     at least 8 rows per bucket; explicit values are rounded up to a power
     of two and clamped into that range. 1 = the single whole-batch bucket
-    (the pre-locality serving behavior, and the B=1 baseline of
-    ``tools/serve_smoke.py --locality-bench``)."""
+    (the pre-locality serving behavior, and the B=1 baseline
+    tests/test_query_locality.py holds the sorted buckets to)."""
     if qpad < 16:
         return 1
     cap = qpad // 8
@@ -298,12 +298,12 @@ def device_merge_final(heap: CandidateState, num_shards: int,
 
 
 def resolve_bucket_size(bucket_size: int, engine: str) -> int:
-    """0 = auto, resolved per engine from measured data: the XLA twin is
-    pair-budget-bound on its low-overhead backend (CPU wall-clock tracks
-    pairs/query 1:1 — bucket 128 doubled 250K/k=8 throughput over 512,
-    round-5 geometry sweep + pair_budget_report.json), while the Pallas
-    kernel pays a real per-while-step cost that favors wider tiles —
-    tpu_tune.py's on-chip sweep ranked 256 (with G2) first.
+    """0 = auto, resolved per engine: the XLA twin is pair-budget-bound on
+    its low-overhead backend (CPU wall-clock tracks pairs/query, so the
+    fine 128 bucket), while the Pallas kernel pays a real per-while-step
+    cost that favors wider tiles (256, with G2). Neither default has been
+    swept on the chip at 1M points: the benchmark's self-join cells run
+    the Pallas one (PERF_LEDGER.jsonl) without comparing it to others.
 
     Checkpoint note: stepwise fingerprints record the RESOLVED values (a
     different bucket geometry is genuinely non-resumable state — the
@@ -317,11 +317,9 @@ def resolve_bucket_size(bucket_size: int, engine: str) -> int:
     if engine == "tiled":
         return 128
     if engine == "pallas_tiled":
-        # tpu_tune.py on-chip sweep (round 5, v5e, 500K/k=8): 256-bucket
-        # cells beat the old 512 default at every LSK_CHUNK_LANES, and
-        # the 256/G2 geometry won the whole grid (552.7K q/s vs 512/G1's
-        # 356.3K) — see tpu_tune_report.json; G2 comes from the
-        # point_group auto below.
+        # 256/G2 (G2 from the point_group auto below) is what the
+        # benchmark's batch_1m_k8 and ring4_4m_k8 cells run; a sweep of
+        # bucket size and group at 1M on the chip is still to be made.
         return 256
     return 512
 
@@ -601,10 +599,10 @@ def _effective_group(point_group: int, npad_local: int,
     (both are powers of two, so the clamped value always divides).
 
     0 = auto per engine, like resolve_bucket_size: the Pallas kernel's
-    tune-sweep winner pairs its 256-bucket default with G2 (fine prune
-    radius, full-width 512-lane tiles — tpu_tune_report.json round 5);
-    the XLA twin's lock-step visit loop measurably loses from grouping
-    (BASELINE.md round-5 A/B), so every other engine resolves to 1.
+    256-bucket default pairs with G2 (fine prune radius, full-width
+    512-lane tiles; unswept at 1M on the chip, see resolve_bucket_size);
+    the XLA twin's lock-step visit loop loses from grouping, so every
+    other engine resolves to 1.
     ``engine`` is deliberately required: a call site that forgot it
     would silently resolve auto to 1 instead of the engine's tuned
     group (checkpoint-recovery implications in resolve_bucket_size)."""
@@ -650,8 +648,9 @@ def _ring_stats(engine: str, tiles_total: int, bucket_size: int,
     VPU work — its dense tile really covers masked buckets,
     ops/tiled.py body). The Pallas kernel counts only KEPT buckets (its
     nvis masks chunk-tail and skip_self buckets before the fold), so its
-    broadcast FLOPs over masked lanes go uncounted — pair_evals-derived
-    MFU is a lower bound there. Compare engines on wall-clock."""
+    broadcast FLOPs over masked lanes go uncounted — a roofline share
+    derived from pair_evals (perfbench/opcount.py over perfbench/peaks.json)
+    is a lower bound there. Compare engines on wall-clock."""
     if engine in ("tiled", "pallas_tiled"):
         _, s_q = choose_buckets(q_rows or 1, bucket_size)
         _, s_p = choose_buckets(p_rows or q_rows or 1, bucket_size)

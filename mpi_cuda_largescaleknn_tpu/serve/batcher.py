@@ -493,8 +493,9 @@ class DynamicBatcher:
         toward a full batch, and deadline-expired ones are failed at pop
         time instead of going stale behind the semaphore. The old policy
         popped first and stalled holding a batch whose width was frozen
-        (BENCH_serve.json depth-2 regression: 68 stalls / 1.57 s on the
-        smoke fixture). The bound itself is unchanged — it is what keeps a
+        (a depth-2 regression on the CPU fixture;
+        tests/test_serve.py ``test_stall_aware_flush_keeps_rows_queued_
+        while_pipe_full`` holds the fix). The bound itself is unchanged — it is what keeps a
         fast producer from piling unmerged device results without limit.
         """
         while True:

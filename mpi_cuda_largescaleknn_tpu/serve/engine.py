@@ -62,8 +62,8 @@ result bit-identical across bucket geometries — ``query_buckets=1``
 (unsorted, the old behavior) and any B produce the same bytes, ties
 included (tests/test_query_locality.py). The tile counters the traversal
 already carries are surfaced as ``tiles_executed`` / ``tiles_skipped``
-engine counters (and /metrics), so the locality win is a number:
-``tools/serve_smoke.py --locality-bench``.
+engine counters (and /metrics), so the locality win is a number
+(tests/test_query_locality.py ``TestTileAccounting``).
 """
 
 from __future__ import annotations
@@ -1101,8 +1101,9 @@ class ResidentKnnEngine:
         position r holds rows [r*qpad/R, (r+1)*qpad/R) of the final
         [qpad] + [qpad, k] arrays — so each host's fetch moves 1/R of the
         result per owned position and the POD's total fetched bytes equal
-        ONE final result, not one per host (the acceptance arithmetic of
-        ``serve_smoke.py --multihost-bench``). Returns
+        ONE final result, not one per host
+        (tests/test_multihost_serve.py
+        ``test_pod_fetch_bytes_are_one_result_per_batch``). Returns
         ``(rows i32[m], dists f32[m], nbrs i32[m, k])`` where ``rows`` are
         CALLER-order row indices (the Morton admission sort already undone
         per row via ``batch.perm``) and ``m`` counts only real (non-pad)
@@ -1226,7 +1227,7 @@ class ResidentKnnEngine:
             "tiles_executed_vpu": self.timers.counter("tiles_executed_vpu"),
             "tiles_skipped_vpu": self.timers.counter("tiles_skipped_vpu"),
             # headline copies of the timers' counters: the stable /stats
-            # API surface loadgen + serve_smoke bind to (timers.report()
+            # API surface tools/loadgen.py binds to (timers.report()
             # nests the same values among phases/histograms for --timings)
             "fetch_bytes": self.timers.counter("fetch_bytes"),
             "result_rows": self.timers.counter("result_rows"),

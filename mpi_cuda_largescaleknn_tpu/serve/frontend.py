@@ -52,8 +52,7 @@ drain-then-fail semantics: the pod is marked broken, requests answer 503
 (not an opaque 500), and when every host probes healthy again with a
 matching fingerprint and a consistent ``next_seq`` the monitor resets the
 sequence stream (the clean restart path). All of it is exercised
-deterministically via serve/faults.py injectors (tests/test_failover.py,
-``serve_smoke --chaos-bench``).
+deterministically via serve/faults.py injectors (tests/test_failover.py).
 
 Shard-local routing (``--routing bounds``): the replicate-everything
 fan-out above makes adding hosts add WORK, not capacity — every host
@@ -73,9 +72,9 @@ pairs whose box lower bound can still beat that query's current k-th
 distance, until every skipped host is CERTIFIED unable to contribute
 (``lb * (1 - slack) > kth_dist2``; the slack covers the engines' f32
 rounding so certification can never skip a true neighbor, ties included).
-Clustered traffic certifies most queries after one host — pod throughput
-then scales with hosts instead of trailing one host
-(``serve_smoke.py --routing-bench``); results stay bit-identical to the
+Clustered traffic certifies most queries after one host, so pod
+throughput can scale with hosts instead of trailing one host (measured
+only on the CPU fixture); results stay bit-identical to the
 replicate-everything pod because slab sharding keeps ids ascending by
 host, making the pod's shard-major tie discipline THE canonical order.
 

@@ -28,8 +28,8 @@ exact default path's compiled program is byte-identical to the pre-tier
 engine. ``RecallPolicy`` maps a target to the CHEAPEST plan whose
 CALIBRATED recall meets it; calibration comes from
 ``tools/recall_harness.py`` (oracle sampling against the exact engine
-per workload shape), whose measured curves also gate the claimed targets
-in CI (``serve_smoke.py --recall-bench`` -> ``recall_compare``).
+per workload shape); tests/test_recall.py gates the claimed targets
+(``TestEngineRecallTier``).
 
 Exact stays the default: no ``recall`` field (or any target >= 1.0)
 means ``plan_for`` returns ``None`` and every downstream layer takes the
@@ -135,8 +135,8 @@ EXACT_PLAN = RecallPlan()
 #: uniform/clustered/sweep workload shapes at the reference fixture
 #: (D=3, k=16, bucket_size 64: worst-workload measured recall 0.91 /
 #: 0.97 / 0.999 for the three plans below, the worst always uniform),
-#: so the claims survive index shapes rougher than the fixture; CI
-#: re-measures them end to end (serve_smoke --recall-bench). The floors
+#: so the claims survive index shapes rougher than the fixture;
+#: tests/test_recall.py re-measures them on every run. The floors
 #: do NOT survive k far above the reference (k=64 halves approx-fast's
 #: uniform recall — a deep heap needs far more of the visit schedule to
 #: fill its tail), which is why the table is K-CONDITIONED: servers

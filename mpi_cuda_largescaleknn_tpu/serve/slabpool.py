@@ -52,8 +52,8 @@ the PR-2 pipeline announces the NEXT admitted batch's routed slab set a
 batch ahead (serve/batcher.py calls ``prefetch_hint`` with the queued
 rows after each dispatch) — so promotions ride under the in-flight
 batch's compute and a well-hinted stream stalls zero times
-(``serve_smoke --streaming-bench`` gates a stall-fraction ceiling at
-index size 4x the device budget, on top of bitwise probe parity).
+(tests/test_slabpool.py: zero stalls once hinted, and bitwise parity
+with a resident engine at index size 4x the device budget).
 
 AOT discipline across the churn: all slab engines are padded to ONE shape
 class (``pad_shard_rows``) and share an ``ExecutableCache`` keyed by that

@@ -56,8 +56,10 @@ def test_operator_export_wins(monkeypatch, tmp_path):
 def test_idempotent_and_repo_root_resolves(monkeypatch):
     _clear(monkeypatch)
     assert enable_persistent_cache() == enable_persistent_cache()
-    # the default path is anchored at the repo root (where bench.py lives)
-    assert os.path.isfile(os.path.join(_REPO_ROOT, "bench.py"))
+    # the default path is anchored at the repo root (where BENCHMARK.json
+    # and chip_smoke.py live)
+    assert os.path.isfile(os.path.join(_REPO_ROOT, "BENCHMARK.json"))
+    assert os.path.isfile(os.path.join(_REPO_ROOT, "chip_smoke.py"))
 
 
 def test_unwritable_dir_warns_but_does_not_raise(monkeypatch, capsys):

@@ -9,6 +9,7 @@ and the exact rescore must still recover the exact top-k)."""
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from mpi_cuda_largescaleknn_tpu.core.types import PAD_SENTINEL
@@ -19,6 +20,7 @@ from mpi_cuda_largescaleknn_tpu.ops.candidates import (
 )
 from mpi_cuda_largescaleknn_tpu.ops.distance import (
     elementwise_dist2,
+    mxu_min_dim,
     mxu_scores,
     norms2,
     rescore_width,
@@ -223,6 +225,41 @@ class TestTiledMxu:
         stp = init_candidates(qp.num_buckets * qp.bucket_size, 4)
         _, tiles0, folds0 = knn_update_tiled(stp, qp, q, with_stats="full")
         assert int(tiles0) == 0 and int(folds0) == 0
+
+
+class TestShippedMxuThreshold:
+    """The shipped default, no override: at the reference's D=3, and at
+    D=8, a bf16 request lowers to the very program the f32 request runs
+    (below ``mxu_min_dim()`` the VPU path is the fast path); at D=64 the
+    matmul form engages. Either way the answer is the f32 path's, bit for
+    bit."""
+
+    @pytest.fixture(autouse=True)
+    def _shipped_default(self, monkeypatch):
+        monkeypatch.delenv("LSK_MXU_MIN_DIM", raising=False)
+
+    @pytest.mark.parametrize("d,same_program",
+                             [(3, True), (8, True), (64, False)])
+    def test_bf16_request_bitwise_f32(self, d, same_program):
+        pts = _with_dups_and_pads(d, seed=80 + d, n=700)
+        q = partition_points(jnp.asarray(random_points(300, seed=90 + d,
+                                                       dim=d)),
+                             bucket_size=64)
+        p = partition_points(jnp.asarray(pts), bucket_size=64)
+        st = init_candidates(q.num_buckets * q.bucket_size, 16)
+        fns = {m: jax.jit(lambda st, q, p, m=m: knn_update_tiled(
+                   st, q, p, with_stats=True, score_dtype=m))
+               for m in ("f32", "bf16")}
+        texts = {m: fn.lower(st, q, p).as_text() for m, fn in fns.items()}
+        assert (texts["f32"] == texts["bf16"]) is same_program
+        assert (d < mxu_min_dim()) is same_program
+        (f32, tiles_f), (b16, tiles_b) = (fns[m](st, q, p)
+                                          for m in ("f32", "bf16"))
+        np.testing.assert_array_equal(np.asarray(f32.dist2),
+                                      np.asarray(b16.dist2))
+        np.testing.assert_array_equal(np.asarray(f32.idx),
+                                      np.asarray(b16.idx))
+        assert int(tiles_f) == int(tiles_b)
 
 
 class TestPallasMxu:

@@ -514,6 +514,52 @@ class TestDegradedMode:
         finally:
             fe.close()
 
+    def test_every_request_answered_under_host_loss(self, routed_pod,
+                                                     reference_engine,
+                                                     clean_faults):
+        """Availability through a host loss: concurrent clients over the
+        whole box all get answers while host 1 is down — rows that needed
+        it flagged per query, every other row exact and bit-identical to
+        the never-failed pod."""
+        from tests.oracle import random_points
+
+        urls, _points, servers = routed_pod
+        fe, base = _build_fe(urls, on_host_loss="degrade")
+        try:
+            servers[1].faults.set_specs("drop:")
+            qs = [random_points(4 + 3 * i, seed=90 + i) for i in range(8)]
+            results, errors = {}, []
+
+            def client(i):
+                try:
+                    results[i] = _post_knn(base, qs[i])
+                except Exception as e:  # noqa: BLE001 - asserted below
+                    errors.append(e)
+
+            ths = [threading.Thread(target=client, args=(i,))
+                   for i in range(len(qs))]
+            for t in ths:
+                t.start()
+            for t in ths:
+                t.join()
+            assert errors == [] and len(results) == len(qs)
+            flagged = 0
+            for i, resp in results.items():
+                want_d, want_n = reference_engine.query(qs[i])
+                exact = np.asarray(resp.get("exact_per_query",
+                                            [True] * len(qs[i])))
+                assert resp["exact"] is bool(exact.all())
+                flagged += int((~exact).sum())
+                np.testing.assert_array_equal(
+                    np.asarray(resp["dists"], np.float32)[exact],
+                    want_d[exact])
+                np.testing.assert_array_equal(
+                    np.asarray(resp["neighbors"], np.int32)[exact],
+                    want_n[exact])
+            assert flagged > 0
+        finally:
+            fe.close()
+
     def test_rejoin_restores_bitwise_parity(self, routed_pod,
                                             reference_engine, clean_faults):
         from tests.oracle import random_points

@@ -520,6 +520,53 @@ class TestServerHitPath:
                      "knn_qcache_evictions_total"):
             assert name in metrics, f"missing {name}"
 
+    def test_http_json_hit_byte_identity(self, server):
+        """JSON wire, same body twice: the hit replays the first answer's
+        exact response bytes."""
+        base = f"http://127.0.0.1:{server.server_address[1]}"
+        q = np.random.default_rng(31).random((16, 3)).astype(np.float32)
+        body = json.dumps({"queries": q.tolist(),
+                           "neighbors": True}).encode()
+
+        def post():
+            req = urllib.request.Request(
+                base + "/knn", data=body,
+                headers={"Content-Type": "application/json"})
+            with urllib.request.urlopen(req, timeout=60) as resp:
+                return resp.read()
+
+        hits = server.qcache.stats()["hits"]
+        first, second = post(), post()
+        assert first == second
+        assert server.qcache.stats()["hits"] >= hits + len(q)
+
+    def test_seeded_revisit_traffic_compiles_nothing(self, server):
+        """Exact replays and jittered revisits at every batch width: the
+        seed radius is a dynamic operand, so cached traffic mints no new
+        program once the shape buckets are warm."""
+        base = f"http://127.0.0.1:{server.server_address[1]}"
+        eng = server.engine
+        rng = np.random.default_rng(37)
+
+        def post(q):
+            req = urllib.request.Request(
+                base + "/knn", data=json.dumps(
+                    {"queries": q.tolist(), "neighbors": True}).encode(),
+                headers={"Content-Type": "application/json"})
+            with urllib.request.urlopen(req, timeout=60) as resp:
+                return json.loads(resp.read())
+
+        before = eng.compile_count
+        seeds = server.qcache.stats()["seeds"]
+        for n in (3, 8, 17, 40, eng.max_batch):
+            anchors = rng.random((n, 3)).astype(np.float32)
+            post(anchors)
+            post(anchors)  # exact replay
+            post((anchors + rng.normal(0, 1e-3, anchors.shape))
+                 .astype(np.float32))  # jittered revisit, seeded
+        assert eng.compile_count == before
+        assert server.qcache.stats()["seeds"] > seeds
+
     def test_seeded_revisit_stream_matches_cold_server(self, server,
                                                        points):
         """Near-duplicate stream through the full server stack (cache

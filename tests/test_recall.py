@@ -202,6 +202,25 @@ class TestEngineRecallTier:
         assert engaged, "no plan dropped a single neighbor — fixture " \
                         "too small to exercise the approximate tier"
 
+    @pytest.mark.parametrize("workload", ["uniform", "clustered", "sweep"])
+    def test_selected_plan_meets_requested_target(self, big_engine,
+                                                  workload):
+        """What a client asks for is what it gets: for each requested
+        target, the plan the policy selects measures at or above that
+        target against the exact engine's ids, on each harness workload
+        shape."""
+        policy = RecallPolicy()
+        q = workload_queries(workload, 256, seed=3, blob_sigma=0.05)
+        exact = _chunked_ids(big_engine, q)
+        for target in (0.85, 0.95, 0.99):
+            plan = policy.plan_for(target)
+            r = (1.0 if plan is None else
+                 measured_recall(_chunked_ids(big_engine, q, plan=plan),
+                                 exact))
+            assert r >= target, \
+                f"target {target} on {workload}: {plan and plan.name} " \
+                f"measured {r:.4f}"
+
     def test_plan_keyed_executables_compile_once(self, big_engine):
         """Each distinct program_key compiles its own AOT executable
         exactly once; reuse (same plan, same width) never retraces, and
@@ -357,6 +376,24 @@ class TestServerRecallContract:
             # interleave approx traffic, then re-check the exact wire
             _post(_base(srv), {"queries": q.tolist(), "recall": 0.9})
 
+    def test_no_recall_probe_ids_bitwise_after_approx_traffic(
+            self, serve_rig):
+        """The exact path's neighbour ids, not only its distances, come
+        back bit for bit through the live server after approximate
+        traffic has run beside them."""
+        eng, srv = serve_rig
+        q = workload_queries("clustered", 32, seed=12)
+        _post(_base(srv), {"queries": q.tolist(), "recall": 0.5,
+                           "neighbors": True})
+        st, out = _post(_base(srv), {"queries": q.tolist(),
+                                     "neighbors": True})
+        want_d, want_i = eng.query(q)
+        assert st == 200
+        assert np.array_equal(np.asarray(out["dists"], np.float32),
+                              np.asarray(want_d, np.float32))
+        assert np.array_equal(np.asarray(out["neighbors"]),
+                              np.asarray(want_i))
+
     def test_full_target_served_exactly(self, serve_rig):
         eng, srv = serve_rig
         q = workload_queries("uniform", 8, seed=6)
@@ -444,6 +481,23 @@ class TestServerRecallContract:
         assert 'knn_recall_requests_total{tier="exact"}' in metrics
         assert "knn_recall_estimated_bucket" in metrics
         assert "knn_recall_estimated_count" in metrics
+
+
+class TestLoadgenRecallTier:
+    def test_every_targeted_request_lands_in_the_approx_tier(
+            self, serve_rig):
+        """The operator load generator's ``recall`` option: every answered
+        request carried the target and was served by the approximate
+        tier."""
+        import tools.loadgen as loadgen
+
+        _eng, srv = serve_rig
+        rep = loadgen.run_load(_base(srv), duration_s=0.5, concurrency=2,
+                               batch=4, seed=4, recall=0.9)
+        tier = rep["recall"]
+        assert rep["ok"] > 0 and tier["target"] == 0.9
+        assert tier["approx_requests"] == rep["ok"]
+        assert tier["approx_share"] == 1.0
 
 
 # ------------------------------------------------------------ streaming tier

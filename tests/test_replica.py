@@ -496,6 +496,46 @@ class TestReplicaGroupsServing:
         finally:
             fe.close()
 
+    @pytest.mark.parametrize("victim", [0, 1, 2, 3])
+    def test_single_host_loss_under_concurrent_load_stays_exact(
+            self, replica_pod, reference_engine, clean_faults, victim):
+        """Any one host of the R=2 pod lost mid-traffic: every concurrent
+        request is still answered, exact and bit-identical — no
+        ``exact: false``, whichever replica of whichever slab went."""
+        from tests.oracle import random_points
+
+        urls, _points, servers, _ = replica_pod
+        fe, base = _build_fe(urls)
+        try:
+            servers[victim].faults.set_specs("drop:")
+            qs = [random_points(4 + 3 * i, seed=500 + 10 * victim + i)
+                  for i in range(8)]
+            results, errors = {}, []
+
+            def client(i):
+                try:
+                    results[i] = _post_knn(base, qs[i])
+                except Exception as e:  # noqa: BLE001 - asserted below
+                    errors.append(e)
+
+            ths = [threading.Thread(target=client, args=(i,))
+                   for i in range(len(qs))]
+            for t in ths:
+                t.start()
+            for t in ths:
+                t.join()
+            assert errors == [] and len(results) == len(qs)
+            for i, resp in results.items():
+                assert resp["exact"] is True
+                assert "exact_per_query" not in resp
+                want_d, want_n = reference_engine.query(qs[i])
+                np.testing.assert_array_equal(
+                    np.asarray(resp["dists"], np.float32), want_d)
+                np.testing.assert_array_equal(
+                    np.asarray(resp["neighbors"], np.int32), want_n)
+        finally:
+            fe.close()
+
     def test_all_replicas_down_degrades_then_rejoins(
             self, replica_pod, reference_engine, clean_faults):
         from tests.oracle import random_points

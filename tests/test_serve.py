@@ -419,8 +419,8 @@ class TestPipelinedBatcher:
                    for r in caplog.records)
 
     def test_stall_aware_flush_keeps_rows_queued_while_pipe_full(self):
-        """The depth-2 regression fix (BENCH_serve.json: 68 stalls/1.57s):
-        the dispatch worker reserves its pipeline slot BEFORE popping, so
+        """The depth-2 regression fix (stalls on the CPU fixture): the
+        dispatch worker reserves its pipeline slot BEFORE popping, so
         while the pipe is FULL queued requests stay in the queue —
         coalescable and deadline-cancellable — instead of being popped and
         held frozen behind the semaphore. Then everything drains exactly."""
@@ -859,13 +859,12 @@ class TestLoadgen:
         assert rep["qps"] > 0 and rep["rows_per_s"] > 0
         for key in ("p50_ms", "p95_ms", "p99_ms"):
             assert rep[key] > 0
-        # the report must be JSON-serializable (it IS the BENCH artifact)
+        # the report must be JSON-serializable (``--out`` writes it)
         json.dumps(rep)
 
     def test_binary_mode_and_server_stats(self, server):
         """The octet-stream wire format over the keep-alive client, plus
-        the embedded /stats pipeline-occupancy scrape serve_smoke relies
-        on."""
+        the embedded /stats pipeline-occupancy scrape."""
         import sys
 
         sys.path.insert(0, "tools")

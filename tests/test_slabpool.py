@@ -26,6 +26,8 @@ Four layers of coverage:
 
 from __future__ import annotations
 
+import contextlib
+import queue
 import threading
 
 import numpy as np
@@ -321,6 +323,24 @@ def parity_rig():
     stream.close()
 
 
+@contextlib.contextmanager
+def _promotion_worker_parked(pool):
+    """Hold the pool's promotion worker off the batches run inside.
+
+    ``dispatch`` hands its wave to the promotion thread before it ensures
+    each slab, so a dispatch thread descheduled for a promotion's length
+    finds the slab already landed and never stalls. Promotions enqueued
+    inside go to a parked queue and reach the worker on exit; a missing
+    slab is then still missing when the dispatch asks for it."""
+    live, pool._pq = pool._pq, queue.Queue()
+    try:
+        yield
+    finally:
+        parked, pool._pq = pool._pq, live
+        while not parked.empty():
+            live.put(parked.get())
+
+
 def _probe_batches(pts, seed=0):
     """Deterministic probe set: random batches, cluster-boundary rows,
     exact-duplicate coordinates (distance-0 cross-slab ties), and a
@@ -402,21 +422,28 @@ class TestStreamingParity:
         # (slab 1's box spans both clusters — the planted outliers — so a
         # B batch routes to {1, 2, 3}), narrow enough that parking at one
         # end of the index evicts the other end's slabs
-        stream.slab_pool.set_device_budget(3 * slab_b)
+        pool = stream.slab_pool
+        pool.set_device_budget(3 * slab_b)
         q_a, q_b = pts[10:18], pts[590:598]  # opposite ends of the index
+        _lb, want = stream._wave1_want(q_b)
+        b_set = set(np.nonzero(want.any(axis=0))[0].tolist())
         stream.query(q_a)  # park the pool at the A end
-        stream.slab_pool.wait_idle(timeout_s=30)
+        assert pool.wait_idle(timeout_s=30)
         stream.prefetch_hint(q_b)  # announce the B batch one batch ahead
-        assert stream.slab_pool.wait_idle(timeout_s=30)
-        before = stream.slab_pool.stats()["stream_stalls"]
+        assert pool.wait_idle(timeout_s=30)
+        assert b_set <= set(pool.resident_slabs())
+        before = pool.stats()["stream_stalls"]
         stream.query(q_b)
-        assert stream.slab_pool.stats()["stream_stalls"] == before
+        assert pool.stats()["stream_stalls"] == before
         # and the un-hinted twin DOES stall after the pool moves away
         stream.query(q_a)
-        stream.slab_pool.wait_idle(timeout_s=30)
-        stream.query(q_b)
-        assert stream.slab_pool.stats()["stream_stalls"] > before
-        stream.slab_pool.set_device_budget(0)
+        assert pool.wait_idle(timeout_s=30)
+        assert not b_set <= set(pool.resident_slabs())
+        with _promotion_worker_parked(pool):
+            stream.query(q_b)
+        assert pool.stats()["stream_stalls"] > before
+        assert pool.wait_idle(timeout_s=30)
+        pool.set_device_budget(0)
 
     def test_slow_promotion_stalls_but_stays_exact(self, parity_rig):
         """faults.py latency on a promotion: the batch STALLS (counted)
@@ -436,7 +463,8 @@ class TestStreamingParity:
             before = stream.slab_pool.stats()
             q = pts[590:598]
             dr, nr = ref.query(q)
-            ds, ns = stream.query(q)
+            with _promotion_worker_parked(stream.slab_pool):
+                ds, ns = stream.query(q)
             after = stream.slab_pool.stats()
             assert np.array_equal(np.asarray(dr, np.float32), ds)
             assert np.array_equal(np.asarray(nr), ns)
@@ -607,6 +635,57 @@ class TestStreamingServing:
         finally:
             srv.close()
             stream.slab_pool.set_device_budget(0)
+
+    def test_served_parity_after_sweep_churn_at_quarter_budget(
+            self, parity_rig):
+        """The index at 4x the device budget (1 of 4 slabs resident),
+        served over HTTP with the query cache off: a probe answers the
+        fully-resident engine's bytes (dists and ids) before AND after a
+        sweep that churns the pool end to end."""
+        import json
+        import urllib.request
+
+        from mpi_cuda_largescaleknn_tpu.serve.server import build_server
+
+        pts, ref, stream = parity_rig
+        pool = stream.slab_pool
+        pool.set_device_budget(stream.slab_device_bytes)
+        srv = build_server(stream, port=0, pipeline_depth=2,
+                           qcache_rows=0)
+        srv.ready = True
+        threading.Thread(target=srv.serve_forever, daemon=True).start()
+        base = f"http://127.0.0.1:{srv.server_address[1]}"
+
+        def post(q):
+            req = urllib.request.Request(
+                base + "/knn", data=json.dumps(
+                    {"queries": q.tolist(), "neighbors": True}).encode(),
+                headers={"Content-Type": "application/json"})
+            with urllib.request.urlopen(req, timeout=120) as resp:
+                out = json.loads(resp.read())
+            return (np.asarray(out["dists"], np.float32),
+                    np.asarray(out["neighbors"], np.int32))
+
+        rng = np.random.default_rng(17)
+        probe = np.concatenate([rng.random((24, 3)).astype(np.float32),
+                                pts[[0, 295, 299, 595, 599]]])
+        want_d, want_n = (np.asarray(a) for a in ref.query(probe))
+        try:
+            d, n = post(probe)
+            assert np.array_equal(d, want_d.astype(np.float32))
+            assert np.array_equal(n, want_n)
+            evictions = pool.stats()["evictions"]
+            sweep = np.linspace(0.1, 0.9, 5)
+            for c in np.concatenate([sweep, sweep[::-1]]):  # there, back
+                post(np.clip(c + rng.normal(0, 0.05, (16, 3)), 0, 1)
+                     .astype(np.float32))
+            assert pool.stats()["evictions"] >= evictions + stream.num_slabs
+            d, n = post(probe)
+            assert np.array_equal(d, want_d.astype(np.float32))
+            assert np.array_equal(n, want_n)
+        finally:
+            srv.close()
+            pool.set_device_budget(0)
 
 
 class TestBatcherPrefetchHint:

@@ -542,6 +542,126 @@ class TestHostWireHttp:
         assert np.array_equal(rows.view(np.uint32),
                               pts[17:101].view(np.uint32))
 
+    @pytest.mark.parametrize("wire", ["d16", "f32"])
+    def test_multi_frame_slab_pull_is_lossless(self, wire_hosts,
+                                               monkeypatch, wire):
+        """A slab larger than one stream chunk arrives as several frames
+        and still decodes to the host's rows bit for bit."""
+        from mpi_cuda_largescaleknn_tpu.serve.frontend import _HostHandler
+        from mpi_cuda_largescaleknn_tpu.serve.replica import pull_slab_rows
+
+        pts, auto_url, _ = wire_hosts
+        monkeypatch.setattr(_HostHandler, "slab_chunk_rows", 60)
+        rows, off = pull_slab_rows(auto_url, wire=wire)
+        assert off == 0 and -(-len(pts) // 60) > 1
+        assert np.array_equal(rows.view(np.uint32), pts.view(np.uint32))
+
+
+# ------------------------------------------ front end: codec vs f32 wire
+
+WIRE_POD_K = 16
+
+
+@pytest.fixture(scope="module")
+def wire_pods():
+    """Slab hosts for four pod shapes over one Morton-sorted point set,
+    with four rows copied across the slab boundary (distance-0 ties
+    between hosts): plain routed, replicated, one host streaming four
+    sub-slabs, and one host forced to the f32 wire."""
+    from mpi_cuda_largescaleknn_tpu.parallel.mesh import get_mesh
+    from mpi_cuda_largescaleknn_tpu.serve.engine import ResidentKnnEngine
+    from mpi_cuda_largescaleknn_tpu.serve.slabpool import (
+        StreamingKnnEngine,
+    )
+
+    pts = _morton_points(2048, seed=70)
+    half = len(pts) // 2
+    pts[half:half + 4] = pts[half - 4:half]
+    kw = dict(mesh=get_mesh(1), engine="tiled", bucket_size=64,
+              max_batch=32, min_batch=16, emit="candidates")
+    eng0 = ResidentKnnEngine(pts[:half], WIRE_POD_K, id_offset=0, **kw)
+    eng1 = ResidentKnnEngine(pts[half:], WIRE_POD_K, id_offset=half, **kw)
+    stream0 = StreamingKnnEngine(points=pts[:half], num_slabs=4,
+                                 k=WIRE_POD_K, id_offset=0, **kw)
+    for e in (eng0, eng1, stream0):
+        e.warmup()
+    servers, urls = {}, {}
+    for name, eng, skw in (("a0", eng0, {}), ("a0b", eng0, {}),
+                           ("a1", eng1, {}), ("a1b", eng1, {}),
+                           ("s0", stream0, {}), ("f1", eng1,
+                                                 {"wire": "f32"})):
+        servers[name], urls[name] = _boot(eng, **skw)
+    pods = {"routed": ["a0", "a1"],
+            "replicated": ["a0", "a0b", "a1", "a1b"],
+            "streaming": ["s0", "a1"],
+            "mixed_f32_host": ["a0", "f1"]}
+    rng = np.random.default_rng(71)
+    centers = rng.random((4, 3))
+    probe = np.concatenate([
+        pts[half - 4:half + 4],
+        np.clip(centers[rng.integers(4, size=12)]
+                + rng.normal(0, 0.02, (12, 3)), 0, 1),
+        rng.random((12, 3)),
+    ]).astype(np.float32)
+    yield {n: [urls[h] for h in hosts] for n, hosts in pods.items()}, probe
+    for srv in servers.values():
+        srv.close()
+    stream0.close()
+
+
+def _probe_frontend(urls, probe, wire):
+    """One probe through a fresh front end at ``wire``; the JSON answer
+    and the front end's candidate-exchange traffic by codec."""
+    from mpi_cuda_largescaleknn_tpu.serve.frontend import build_frontend
+
+    fe = build_frontend(urls, port=0, max_delay_s=0.002, pipeline_depth=2,
+                        wire=wire, qcache_rows=0, start_monitor=False)
+    fe.ready = True
+    threading.Thread(target=fe.serve_forever, daemon=True).start()
+    try:
+        req = urllib.request.Request(
+            f"http://127.0.0.1:{fe.server_address[1]}/knn",
+            data=json.dumps({"queries": probe.tolist(),
+                             "neighbors": True}).encode(),
+            headers={"Content-Type": "application/json"})
+        with urllib.request.urlopen(req, timeout=120) as r:
+            resp = json.loads(r.read())
+        traffic = fe.fanout.stats()["wire"]["traffic"].get("candidates",
+                                                            {})
+    finally:
+        fe.close()
+    return resp, traffic
+
+
+class TestFrontendWireParity:
+    @pytest.mark.parametrize("pod", ["routed", "replicated", "streaming",
+                                     "mixed_f32_host"])
+    def test_codec_answers_equal_f32_wire(self, wire_pods, pod):
+        """Negotiated codecs change bytes on the wire, never the answer:
+        dists, neighbour ids (the cross-host distance-0 ties included)
+        and exact flags equal the f32 wire's, on every pod shape."""
+        pods, probe = wire_pods
+        f32, f32_traffic = _probe_frontend(pods[pod], probe, "f32")
+        auto, auto_traffic = _probe_frontend(pods[pod], probe, "auto")
+        assert set(f32_traffic) == {"f32"}
+        assert "q16" in auto_traffic
+        assert auto["dists"] == f32["dists"]
+        assert auto["neighbors"] == f32["neighbors"]
+        assert auto.get("exact", True) is True
+        assert f32.get("exact", True) is True
+
+    def test_q16_candidate_bytes_per_row_under_045_of_f32(self, wire_pods):
+        """The compressed exchange's reason to exist, counted in bytes:
+        q16 candidate rows cost at most 0.45 x the f32 rows."""
+        pods, probe = wire_pods
+        _f32, f32_traffic = _probe_frontend(pods["routed"], probe, "f32")
+        _auto, auto_traffic = _probe_frontend(pods["routed"], probe, "auto")
+        f32_bpr = f32_traffic["f32"]["bytes"] / f32_traffic["f32"]["rows"]
+        q16_bpr = (auto_traffic["q16"]["bytes"]
+                   / auto_traffic["q16"]["rows"])
+        assert f32_bpr == 8 * WIRE_POD_K
+        assert q16_bpr <= 0.45 * f32_bpr
+
 
 # ------------------------------------------- skip-cold drift admission
 

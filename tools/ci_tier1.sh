@@ -14,77 +14,6 @@ lskrc=$?
 
 set -o pipefail; rm -f /tmp/_t1.log; timeout -k 10 870 env JAX_PLATFORMS=cpu python -m pytest tests/ -q -m 'not slow' --continue-on-collection-errors -p no:cacheprovider -p no:xdist -p no:randomly 2>&1 | tee /tmp/_t1.log; rc=${PIPESTATUS[0]}; echo DOTS_PASSED=$(grep -aE '^[.FEsx]+( *\[ *[0-9]+%\])?$' /tmp/_t1.log | tr -cd . | wc -c)
 
-# Serving bench trajectory (ROADMAP): loadgen q/s + p50/p95/p99 at pipeline
-# depth 1 vs 2 -> BENCH_serve.json, next to the batch BENCH_r*.json series.
-# Runs regardless of the pytest rc (the suite carries known pallas-API-drift
-# failures on the container's jax pin — see ROADMAP), but only reachable
-# when the test step completed rather than timing out (timeout exits 124).
-# Oracle-exactness is the only gate in its exit code; throughput numbers on
-# shared CI boxes are trajectory data, not a pass/fail bar. SERVE_BENCH=0
-# skips (e.g. when iterating on an unrelated subsystem).
-if [ "${SERVE_BENCH:-1}" != "0" ] && [ "$rc" -ne 124 ]; then
-  # --locality-bench adds the clustered-vs-uniform query-locality section
-  # (locality_compare): Morton admission + multi-bucket traversal vs the
-  # single-bucket baseline, gated on oracle-exactness like the rest.
-  # --multihost-bench adds the pod-serving section (multihost_compare):
-  # 2 simulated host processes over one global mesh + the fan-out front
-  # end vs a single-process twin — deterministic fetched-bytes-per-pod
-  # ratio (~hosts x below per-host fetch), oracle-exact gated
-  # --kernel-bench adds the distance-kernel section (kernel_compare):
-  # elementwise VPU vs MXU matmul-form scoring at D in {3, 8, 64},
-  # gated on MXU-vs-VPU bitwise exactness; speedups are trajectory data
-  # --routing-bench adds the shard-local routing section
-  # (routing_compare): the 2-host pod at --routing bounds vs --routing
-  # off on clustered + uniform workloads — gated on the probe batch
-  # being bitwise identical between the two (tie ids included) and
-  # oracle-exact; multihost_compare additionally gates on its
-  # qps_ratio_pod_vs_single regression floor
-  # --chaos-bench adds the fault-tolerance section (chaos_compare): one
-  # routed host killed mid-load via a deterministic fault-injected
-  # outage — gated on availability under single-host loss (degrade mode
-  # keeps answering, flagged exact:false) AND post-rejoin bitwise parity
-  # --replica-bench adds the replication/handoff section
-  # (replica_compare): a rolling single-host kill across an R=2 routed
-  # pod with a warm standby — gated on ZERO exact:false responses,
-  # availability >= 0.999, and the post-handoff probe being bitwise
-  # identical to the never-failed answers (the adopted slab proves
-  # itself); q/s at R=2 vs R=1 is the trajectory number
-  # --streaming-bench adds the tiered-slab section (streaming_compare):
-  # the sweep workload churning a slab pool at index size 4x the device
-  # budget — gated on bitwise probe parity vs a fully-resident engine
-  # (cold AND post-churn) plus a stream-stall-fraction ceiling (the
-  # bounds-driven prefetcher must hide promotions under compute)
-  # --recall-bench adds the recall-SLO tier section (recall_compare):
-  # every requested recall target measured against the exact engine's
-  # ids per workload shape over a clustered index — gated on measured
-  # recall >= the requested target on every workload, approx-tier q/s
-  # >= 3x exact on clustered (engine tier), the no-recall default path
-  # staying bitwise identical through the live server, and the
-  # exact:false / X-Knn-* / stats / metrics response contract
-  # --wire-bench adds the quantized-wire section (wire_compare): the
-  # q16 candidate exchange + x32 survivor re-fetch vs the f32 wire on
-  # routed/replicated/streaming/mixed-codec pods — gated on bitwise
-  # probe parity per pod, exchange bytes-per-row <= 0.45x f32, and the
-  # d16 slab handoff being lossless with a paced-transfer seconds
-  # ratio <= 0.6x f32
-  # --tenancy-bench adds the multi-index tenancy section
-  # (tenancy_compare): N tenants under zipf-skewed traffic sharing ONE
-  # device byte budget vs N isolated single-tenant servers at equal
-  # total memory — gated on aggregate goodput >= 1.3x isolated, cold
-  # tenant p99 bounded, per-tenant bitwise parity vs the isolated
-  # twins, and compile count staying flat across tenants
-  # --cache-bench adds the certified query-cache section
-  # (cache_compare): a revisit-heavy stream (exact replays + jittered
-  # revisits) at a cache-enabled server vs a cache-off twin over one
-  # shared engine — gated on revisit q/s >= 1.5x the twin,
-  # seeded-vs-unseeded bitwise parity, hit-path responses
-  # byte-identical, and compile count staying flat under seeded traffic
-  timeout -k 10 4500 python tools/serve_smoke.py --duration 2 --trials 3 \
-      --locality-bench --multihost-bench --kernel-bench --routing-bench \
-      --chaos-bench --replica-bench --streaming-bench --recall-bench \
-      --wire-bench --tenancy-bench --cache-bench \
-      --out BENCH_serve.json >/dev/null || { brc=$?; [ "$rc" -eq 0 ] && rc=$brc; }
-fi
 # the lskcheck gate blocks even when the tests pass (and never masks a
 # test failure — the first nonzero status wins)
 [ "$rc" -eq 0 ] && rc=$lskrc

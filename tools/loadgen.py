@@ -15,12 +15,10 @@ Workloads (``--workload``): ``uniform`` draws every query independently in
 (``--scale`` stands in for the index bounding box — match it to the data)
 and each REQUEST samples one blob with ``--blob-sigma`` spread: the
 one-user-one-region pattern the serving engine's Morton-sorted multi-bucket
-traversal exists to exploit (``serve_smoke.py --locality-bench`` drives
-both and compares tile counts). ``sweep`` drifts a blob window along the
+traversal exists to exploit. ``sweep`` drifts a blob window along the
 box diagonal over ``--sweep-period`` seconds: the hot region MOVES, so a
 tiered slab index (serve/slabpool.py) churns through real
-eviction/readmission cycles — clustered/uniform never evict once warm
-(``serve_smoke.py --streaming-bench`` drives it).
+eviction/readmission cycles — clustered/uniform never evict once warm.
 
     python tools/loadgen.py --url http://127.0.0.1:8080 --duration 10 \
         --concurrency 8 --batch 16 [--qps 500] [--workload clustered] \
@@ -179,8 +177,8 @@ def _server_pipeline_stats(url: str, timeout_s: float) -> dict | None:
         "mean_batch_rows": b.get("mean_batch_rows"),
         "engine": stats.get("engine", {}).get("engine"),
         "compile_count": stats.get("engine", {}).get("compile_count"),
-        # merge placement + cumulative fetch accounting: serve_smoke's
-        # host-vs-device comparison derives bytes-per-row from these
+        # merge placement + cumulative fetch accounting: bytes per
+        # result row for a host-vs-device merge comparison
         "merge": stats.get("engine", {}).get("merge"),
         "fetch_bytes": stats.get("engine", {}).get("fetch_bytes"),
         "result_rows": stats.get("engine", {}).get("result_rows"),

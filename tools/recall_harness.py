@@ -12,8 +12,8 @@ its worst calibrated workload delivered, with slack for workload drift.
 The output JSON is a ready-to-serve policy table
 (``{"plans": [...]}``, the ``RecallPolicy.from_file`` format — point
 ``tpuknn-serve --recall-policy`` at it), plus the full measured matrix so
-the calibration is auditable. ``serve_smoke.py --recall-bench`` re-runs
-the same measurement end to end over HTTP and gates the claims in CI.
+the calibration is auditable. tests/test_recall.py re-runs the same
+measurement on the built-in plans and gates their claims.
 
     python tools/recall_harness.py --points 16384 --k 16 \
         --queries 512 --margin 0.02 --out recall_policy.json
