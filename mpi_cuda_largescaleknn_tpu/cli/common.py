@@ -50,7 +50,8 @@ TPU-side options (no reference analogue):
   --timings         print phase timings as JSON to stderr: calls and
                     seconds per phase (shard_and_pad, ring, extract) and
                     per ring span (ring.stage ... ring.wait), and the
-                    ring.program_builds counter
+                    ring.program_builds and ring.warm_fold_passes
+                    counters
   --checkpoint-dir D  snapshot exchange state between rounds (both
                     pipelines); an interrupted run relaunched with the same
                     args resumes at the lost round

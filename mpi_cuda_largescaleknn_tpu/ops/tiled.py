@@ -38,18 +38,19 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from mpi_cuda_largescaleknn_tpu.core.types import CandidateState
+from mpi_cuda_largescaleknn_tpu.core.types import PAD_SENTINEL, CandidateState
 from mpi_cuda_largescaleknn_tpu.ops.candidates import (
     init_candidates,
     merge_candidates,
 )
 from mpi_cuda_largescaleknn_tpu.ops.distance import (
-    elementwise_dist2,
     mxu_min_dim,
     norms2,
     score_tile,
     validate_score_dtype,
 )
+from mpi_cuda_largescaleknn_tpu.ops.pallas import is_tpu_backend
+from mpi_cuda_largescaleknn_tpu.ops.pallas.warm_start import warm_fold
 from mpi_cuda_largescaleknn_tpu.ops.partition import (
     BucketedPoints,
     nearest_first_order,
@@ -78,7 +79,9 @@ def _worst2(hd2: jnp.ndarray, qvalid: jnp.ndarray) -> jnp.ndarray:
 
 
 def warm_start_self(q: BucketedPoints, k: int,
-                    max_radius: float = jnp.inf) -> CandidateState:
+                    max_radius: float = jnp.inf, *, decode_ids: bool = True,
+                    with_passes: bool = False,
+                    interpret: bool | None = None):
     """Exact top-k of each query's OWN bucket, as the initial candidate
     state for a self-join traversal.
 
@@ -89,43 +92,55 @@ def warm_start_self(q: BucketedPoints, k: int,
     adopts its first k candidates — ~k full-tile passes per query bucket,
     the dominant cost at k=100. Pre-folding the self bucket (each query's
     nearest neighborhood by construction: it shares the query's tight AABB)
-    with one batched ``top_k``+merge fills every row exactly and shrinks
-    the entry radius, so the traversal starts warm. Callers MUST then mask
-    the self bucket out of the traversal (``skip_self``) — folding it twice
-    would corrupt the candidate rows with duplicates.
+    fills every row exactly and shrinks the entry radius, so the traversal
+    starts warm. One Pallas kernel does it (ops/pallas/warm_start.py): each
+    program scores a block of the bucket's rows against the bucket's own
+    points and folds the tile into fresh rows, with no sort. Callers MUST
+    then mask the self bucket out of the traversal (``skip_self``) —
+    folding it twice would corrupt the candidate rows with duplicates.
 
-    Semantics match the fold exactly: strict-< adoption against the
-    ``max_radius`` cutoff slots (merge_candidates' stable existing-first
-    sort), pad lanes carry +inf distance, self counts as neighbor 0.
+    Semantics match the fold exactly (it is the traversal's fold):
+    strict-< adoption against the ``max_radius`` cutoff slots, ties kept
+    in lane order, pad lanes carry +inf distance, self counts as
+    neighbor 0.
 
     Candidate rows are independent, so a coarsened self-join (the
     ``point_group`` knob) simply passes ``coarsen_buckets(q, group)`` here:
     the returned rows are in the same flat order (the coarsening is a
     reshape) and each query pre-folds its containing coarse bucket — the
     traversal's skip mask must then use the same ``group``.
+
+    ``decode_ids=False`` is for callers that read only distances: the
+    adopted entries then keep the kernel's flat lane positions into
+    ``q.ids`` (non-negative, so a later fold passes them through) instead
+    of being decoded to ids by a gather; the distances are the same bits.
+    ``with_passes`` also returns the i32 tile-scan passes the folds ran,
+    summed over the programs. ``interpret=None`` picks interpret mode off
+    the TPU.
     """
+    if interpret is None:
+        interpret = not is_tpu_backend()
     num_qb, s = q.ids.shape
-    init = init_candidates(num_qb * s, k, max_radius)
-    hd2 = init.dist2.reshape(num_qb, s, k)
-    hidx = init.idx.reshape(num_qb, s, k)
-
-    def one(args):
-        pts, ids, cd2, cidx = args            # [S,D],[S],[S,k],[S,k]
-        d2 = elementwise_dist2(pts, pts)      # [S, S]
-        # pad lanes: PAD_SENTINEL coords already overflow to +inf, the
-        # mask makes it explicit (and safe against sentinel changes)
-        d2 = jnp.where(ids[None, :] >= 0, d2, jnp.inf)
-        st = merge_candidates(CandidateState(cd2, cidx), d2,
-                              jnp.broadcast_to(ids[None, :], d2.shape))
-        return st.dist2, st.idx
-
-    # sequential over buckets would serialize thousands of small ops (the
-    # round-3 lesson); batch_size vmaps blocks of buckets per map step,
-    # sized so the [batch, S, S] tile stays ~128MB whatever S is
-    batch = max(1, min(64, num_qb, (1 << 25) // max(s * s, 1)))
-    hd2, hidx = lax.map(one, (q.pts, q.ids, hd2, hidx), batch_size=batch)
-    return CandidateState(hd2.reshape(num_qb * s, k),
-                          hidx.reshape(num_qb * s, k))
+    pts, ids = q.pts, q.ids
+    lane_pad = (-s) % 128
+    if lane_pad:
+        pts = jnp.pad(pts, ((0, 0), (0, lane_pad), (0, 0)),
+                      constant_values=PAD_SENTINEL)
+        ids = jnp.pad(ids, ((0, 0), (0, lane_pad)), constant_values=-1)
+    # lanes-major points, then a row that is +inf on pad lanes (id < 0)
+    pen = jnp.where(ids >= 0, 0.0, jnp.inf).astype(jnp.float32)
+    p_t = jnp.concatenate([jnp.swapaxes(pts, 1, 2), pen[:, None, :]],
+                          axis=1)                       # [Bq, D+1, T_pad]
+    r2 = init_candidates(1, 1, max_radius).dist2        # [1, 1]
+    d2, pos, passes = warm_fold(r2, q.pts, p_t, k=k, lanes=s,
+                                interpret=interpret)
+    if decode_ids:
+        pos = jnp.where(pos >= 0, jnp.take(q.ids.reshape(-1),
+                                           jnp.maximum(pos, 0)), pos)
+    out = CandidateState(d2, pos)
+    if with_passes:
+        return out, jnp.sum(passes).astype(jnp.int32)
+    return out
 
 
 def tile_schedule_slots(num_pb: int, visits_per_step: int = 8) -> int:

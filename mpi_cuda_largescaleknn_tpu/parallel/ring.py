@@ -367,6 +367,9 @@ def _make_ring_fns(k, max_radius, engine, query_tile, point_tile, bucket_size,
 
     - init_fn(pts_local, ids_local) -> (stationary, shard_pair, heap)
       (classic path: the slab is both tree shard and queries)
+    - init_from_q(q) -> (stationary, shard_pair, heap, warm_passes)
+      (tiled path, from the hoisted partition; ``warm_passes`` is i32[1]:
+      the warm start's fold passes on this device, 0 for a cold heap)
     - shard_init_fn(pts_local, ids_local) -> shard (tree side only; drivers
       pair it as (shard, shard) — see below)
     - query_init_fn(qpts_local, qids_local) -> (stationary, heap)
@@ -403,11 +406,10 @@ def _make_ring_fns(k, max_radius, engine, query_tile, point_tile, bucket_size,
     # warm start only applies to self-joins on ONE shared partition (query
     # bucket b IS point bucket b in round 0) — and only pays where the
     # fold's PASS count is the cost: the Pallas kernel. The XLA twin's
-    # width-2k sort-merge saves nothing from a warm heap, and the warm
-    # start's own top_k+merge cost REGRESSED it 20% at 500K/k=100 on the
-    # CPU fixture (round-5 A/B vs the round-4 tree) — so the twin stays
-    # cold. Chunked drivers partition queries separately and always stay
-    # cold.
+    # width-2k sort-merge saves nothing from a warm heap (a sort-based
+    # warm start REGRESSED it 20% at 500K/k=100 on the CPU fixture, round
+    # 5), so the twin stays cold. Chunked drivers partition queries
+    # separately and always stay cold.
     warm_start = warm_start and engine == "pallas_tiled"
 
     if use_tiled:
@@ -432,12 +434,17 @@ def _make_ring_fns(k, max_radius, engine, query_tile, point_tile, bucket_size,
                 # exact top-k of every query's own (containing) resident
                 # bucket, folded before the traversal — round 0's kernel
                 # then masks that bucket (skip_self below). Rows come back
-                # in fine order: the coarsening is a reshape
-                heap = warm_start_self(pc, k, max_radius)
+                # in fine order: the coarsening is a reshape. A
+                # distance-only heap keeps the kernel's lane positions
+                heap, passes = warm_start_self(pc, k, max_radius,
+                                               decode_ids=with_ids,
+                                               with_passes=True)
+                passes = passes[None]
             else:
                 _, heap = query_from_q(q)
+                passes = pvary(jnp.zeros((1,), jnp.int32))
             shard = _resident_shard(pc)
-            return q, (shard, shard), heap
+            return q, (shard, shard), heap, passes
 
         def fold_one(q, shard, heap, sskip=None):
             # the resident shard keeps its OWN bucket geometry (it may differ
@@ -618,9 +625,9 @@ def _warm_tiles(engine: str, npad_local: int, bucket_size: int,
                 num_shards: int) -> int:
     """[S, S] tiles the warm start scores (one per bucket, every device) —
     counted into executed-work stats alongside the kernel's measured tile
-    counts, since warm_start_self does that distance work in XLA before
-    the traversal ever runs (pallas_tiled self-join drivers only — the
-    twin stays cold, see _make_ring_fns)."""
+    counts, since warm_start_self does that distance work in its own
+    kernel before the traversal runs (pallas_tiled self-join drivers only
+    — the twin stays cold, see _make_ring_fns)."""
     if engine != "pallas_tiled":
         return 0
     return num_shards * choose_buckets(npad_local, bucket_size)[0]
@@ -628,10 +635,12 @@ def _warm_tiles(engine: str, npad_local: int, bucket_size: int,
 
 def _ring_stats(engine: str, tiles_total: int, bucket_size: int,
                 n_q_device_rounds: int, *, q_rows: int | None = None,
-                p_rows: int | None = None, point_group: int = 1) -> dict:
+                p_rows: int | None = None, point_group: int = 1,
+                warm_fold_passes: int = 0) -> dict:
     """Executed-work stats of a run of ``engine`` — the kernel the
     driver's program traced, reported back as ``stats["engine"]``: distance
-    pairs actually scored.
+    pairs actually scored, and the warm start's fold passes (0 where the
+    heap started cold).
 
     Tiled engines report measured tile counts (pruning makes the count
     data-dependent); one tile is [S_q, S_p] where S are the ACTUAL padded
@@ -661,12 +670,13 @@ def _ring_stats(engine: str, tiles_total: int, bucket_size: int,
         # the stack-free traversal is bounds-pruned and uninstrumented:
         # all-pairs would overstate executed work by orders of magnitude
         return {"engine": engine, "pair_evals": 0, "tiles": 0,
-                "flops_per_pair": 8,
+                "flops_per_pair": 8, "warm_fold_passes": 0,
                 "note": "tree engine work is pruned and not counted"}
     else:
         pair_evals = int(n_q_device_rounds)
     return {"engine": engine, "pair_evals": pair_evals,
-            "tiles": int(tiles_total), "flops_per_pair": 8}
+            "tiles": int(tiles_total), "flops_per_pair": 8,
+            "warm_fold_passes": int(warm_fold_passes)}
 
 
 # bounded like _partition_smaps: tests build many meshes and configurations
@@ -685,7 +695,9 @@ def _ring_program(mesh, k, max_radius, engine, query_tile, point_tile,
     The program returns ``(dists, hd2, hidx, tiles)`` with ``with_ids``
     and ``(dists, tiles)`` without: a distance-only program neither
     decodes neighbour ids after its folds nor scatters candidate rows
-    back (``_make_ring_fns``), and it is a configuration of its own."""
+    back (``_make_ring_fns``), and it is a configuration of its own.
+    ``tiles`` holds, per device, one slot per round and then the warm
+    start's fold passes, so one fetch reads both."""
     init_fn, round_fn, final_fn, _sif, _qif, init_from_q, _qfq, kernel = \
         _make_ring_fns(k, max_radius, engine, query_tile, point_tile,
                        bucket_size, num_shards, warm_start=True,
@@ -695,9 +707,10 @@ def _ring_program(mesh, k, max_radius, engine, query_tile, point_tile,
 
     def body(pts_local, ids_local, q_local=None):
         if q_local is not None:
-            stationary, pair, heap = init_from_q(q_local)
+            stationary, pair, heap, warm = init_from_q(q_local)
         else:
             stationary, pair, heap = init_fn(pts_local, ids_local)
+            warm = pvary(jnp.zeros((1,), jnp.int32))
 
         def round_body(i, carry):
             pair, hd2, hidx, tiles = carry
@@ -718,7 +731,8 @@ def _ring_program(mesh, k, max_radius, engine, query_tile, point_tile,
                             jnp.int32(total_rounds - 1), rotate=False)
         tiles = jax.lax.dynamic_update_index_in_dim(
             tiles, t[0], total_rounds - 1, 0)
-        return final_fn(stationary, st, pts_local.shape[0]) + (tiles,)
+        return (final_fn(stationary, st, pts_local.shape[0])
+                + (jnp.concatenate([tiles, warm]),))
 
     shard_spec = P(AXIS)
     # interpret-mode pallas kernels re-evaluate a vma-less kernel jaxpr with
@@ -763,9 +777,12 @@ def ring_knn(points_sharded: jnp.ndarray, ids_sharded: jnp.ndarray, k: int,
         configuration the process had not built before) and
         ``ring.exchange_bytes`` (what every device of the mesh sends in
         the job's rotations, counted from static shapes; 0 at one shard,
-        and ``stats["exchange_bytes"]``), and ``ring.id_decode_folds``
+        and ``stats["exchange_bytes"]``), ``ring.id_decode_folds``
         (the folds whose neighbour ids the job decoded: every fold when
-        ``return_candidates``, else 0).
+        ``return_candidates``, else 0) and, with ``return_stats``,
+        ``ring.warm_fold_passes`` (the warm start's tile-scan passes,
+        summed over its programs and devices; 0 where the heap starts
+        cold, and ``stats["warm_fold_passes"]``).
       return_candidates: also return the final candidate rows. It picks
         the program: without it the job reads distances only, and its
         program decodes no ids and scatters no candidate rows
@@ -821,14 +838,17 @@ def ring_knn(points_sharded: jnp.ndarray, ids_sharded: jnp.ndarray, k: int,
         out += (CandidateState(*outs[1:3]),)
     if return_stats:
         with timers.phase("ring.wait"):
-            tiles_total = int(np.asarray(tiles).sum())
+            slots = np.asarray(tiles).reshape(num_shards, total_rounds + 1)
+        tiles_total = int(slots[:, :-1].sum())
+        warm_passes = int(slots[:, -1].sum())
+        timers.count("ring.warm_fold_passes", warm_passes)
         stats = _ring_stats(
             kernel, tiles_total
             + _warm_tiles(engine, npad_local, bucket_size, num_shards),
             bucket_size,
             num_shards * num_shards * npad_local * npad_local,
             q_rows=npad_local, p_rows=npad_local,
-            point_group=point_group)
+            point_group=point_group, warm_fold_passes=warm_passes)
         out += (dict(stats, exchange_bytes=exchange_bytes,
                      neighbor_ids=bool(return_candidates)),)
     return out if len(out) > 1 else out[0]
@@ -909,10 +929,11 @@ def ring_knn_stepwise(points_sharded: jnp.ndarray, ids_sharded: jnp.ndarray,
                        bucket_size, num_shards, warm_start=not resuming,
                        point_group=point_group, score_dtype=score_dtype)
 
+    warm = None
     if init_from_q is not None:
         q_parts = partition_sharded(pts, ids, mesh, bucket_size)
-        stationary, pair, heap = smap(init_from_q, 1,
-                                      (spec, spec, spec))(q_parts)
+        stationary, pair, heap, warm = smap(init_from_q, 1,
+                                            (spec,) * 4)(q_parts)
     else:
         stationary, pair, heap = smap(init_fn, 2,
                                       (spec, spec, spec))(pts, ids)
@@ -973,7 +994,8 @@ def ring_knn_stepwise(points_sharded: jnp.ndarray, ids_sharded: jnp.ndarray,
             kernel, tiles_total, bucket_size,
             folds * num_shards * npad_local * npad_local,
             q_rows=npad_local, p_rows=npad_local,
-            point_group=point_group),)
+            point_group=point_group,
+            warm_fold_passes=0 if warm is None else int(np.sum(warm))),)
     return out if len(out) > 1 else out[0]
 
 
@@ -1342,7 +1364,8 @@ def ring_knn_chunked(points_sharded: jnp.ndarray, ids_sharded: jnp.ndarray,
             return {"engine": kernel,
                     "pair_evals": tiles_total * num_shards * chunk_rows
                     * s_p * point_group,
-                    "tiles": tiles_total, "flops_per_pair": 8}
+                    "tiles": tiles_total, "flops_per_pair": 8,
+                    "warm_fold_passes": 0}
         return _ring_stats(
             kernel, tiles_total, bucket_size,
             chunks_run * num_shards * num_shards * chunk_rows * npad_local,

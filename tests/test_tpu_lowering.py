@@ -80,6 +80,35 @@ def test_traversal_kernel_lowers_for_tpu(bucket_size, group, k, warm):
     _export_tiled(16384, k, bucket_size, group, warm)
 
 
+def _warm_start_fn(k):
+    def f(pts, ids, lo, hi):
+        pc = BucketedPoints(pts, ids, lo, hi, ids)
+        st, passes = warm_start_self(pc, k, decode_ids=False,
+                                     with_passes=True, interpret=False)
+        return st.dist2, st.idx, passes
+    return f
+
+
+def _warm_start_shapes(sds, group=2):
+    """The warm start's operands at 1M points: bucket 256 gives B=4096
+    fine buckets of S=248 rows, G=2 makes [2048, 496] coarse buckets."""
+    from mpi_cuda_largescaleknn_tpu.ops.partition import choose_buckets
+
+    b, s = choose_buckets(1_000_000, 256)
+    assert (b, s) == (4096, 248)
+    bc = b // group
+    return (sds((bc, group * s, 3), jnp.float32),
+            sds((bc, group * s), jnp.int32), sds((bc, 3), jnp.float32),
+            sds((bc, 3), jnp.float32))
+
+
+@pytest.mark.parametrize("k", [8, 100])
+def test_warm_start_kernel_lowers_for_tpu(k):
+    exp = jax.export.export(jax.jit(_warm_start_fn(k)), platforms=["tpu"])(
+        *_warm_start_shapes(jax.ShapeDtypeStruct))
+    assert b"tpu_custom_call" in exp.mlir_module_serialized
+
+
 def test_flat_kernel_lowers_for_tpu():
     from mpi_cuda_largescaleknn_tpu.ops.pallas.knn_bf import knn_update_pallas
 
@@ -175,6 +204,22 @@ def test_traversal_kernel_compiles_for_v5e(k, one_chip, no_persistent_cache):
         sds((bc, group * s), i32), sds((bc, 3), f32),
         sds((bc, 3), f32)).compile()
     _check_compiled(compiled)
+
+
+@pytest.mark.parametrize("k", [8, 100])
+def test_warm_start_kernel_compiles_for_v5e(k, one_chip, no_persistent_cache):
+    """The self-join's warm start at 1M points (S=248, G=2): one program a
+    fine query bucket, no sort."""
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(_warm_start_fn(k)).lower(
+        *_warm_start_shapes(sds)).compile()
+    _check_compiled(compiled)
+    text = compiled.as_text()
+    assert " sort(" not in text
+    # not the traversal kernel's name, which its roofline reads
+    assert "%warm_fold" in text and "%_run" not in text
 
 
 def test_flat_kernel_compiles_for_v5e(one_chip, no_persistent_cache):
